@@ -36,4 +36,6 @@ def run(quick: bool = False):
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+    compile_cache.enable()
     run()

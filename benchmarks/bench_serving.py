@@ -150,6 +150,8 @@ def tuned_reorder_stream(n_graphs: int = 16):
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+    compile_cache.enable()
     import argparse
 
     ap = argparse.ArgumentParser()

@@ -152,6 +152,8 @@ def run(smoke: bool = False):
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+    compile_cache.enable()
     import argparse
 
     ap = argparse.ArgumentParser()

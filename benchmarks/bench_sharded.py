@@ -5,8 +5,10 @@ Three axes, one report (``reports/bench_sharded.json``):
 * **host-device scaling** — the real :class:`~repro.core.pipeline
   .ShardedRunner` wall clock on {1, 2, 4, 8} forced host devices.  The
   device count binds when jax initializes, so each count runs in a
-  subprocess with ``XLA_FLAGS=--xla_force_host_platform_device_count=N``.
-  On a CPU host the shards share the same silicon — this measures the
+  subprocess with ``XLA_FLAGS=--xla_force_host_platform_device_count=N``
+  and ``JAX_PLATFORMS=cpu``: each child measures forced host devices by
+  design and never touches an accelerator, which belongs to one process.
+  The shards share the same silicon — this measures the
   *overhead* of the shard_map + all-gather path (near-parity is the win),
   not a speedup; the speedup axis is simulated.
 * **simulated chip scaling** — the multi-chip cost model
@@ -72,7 +74,8 @@ def run_device_scaling(smoke: bool):
     counts = (1, 2) if smoke else DEVICE_COUNTS
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     py = os.environ.get("PYTHONPATH", "")
-    env = dict(os.environ, PYTHONPATH=src + (os.pathsep + py if py else ""))
+    env = dict(os.environ, PYTHONPATH=src + (os.pathsep + py if py else ""),
+               JAX_PLATFORMS="cpu")
     rows = []
     for n_dev in counts:
         script = _WORKER % (n_dev, n_dev, n_vertices, n_edges, 2, repeats)
@@ -263,4 +266,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+    compile_cache.enable()
     main()

@@ -59,6 +59,8 @@ def run(quick: bool = False, smoke: bool = False, layers: int = 1):
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+    compile_cache.enable()
     import argparse
 
     ap = argparse.ArgumentParser()

@@ -26,9 +26,12 @@ from __future__ import annotations
 
 import argparse
 
+import jax
+
 from repro.core import compiler, isa, pipeline, reorder, simulator, tiling
 from repro.gnn import graphs, models
 from repro.kernels.tile_spmm import ops as tops
+from repro.kernels.tile_spmm.kernel import interpret_mode
 
 from .common import fmt_table, timeit, write_report
 
@@ -164,14 +167,14 @@ def bucketing_study(g, quick: bool = False):
     params = models.init_params(tr)
     inputs = models.init_inputs(tr, g)
     bt = tiling.bucket_tiles(ts, 4)
-    # NB: tops.spmm defaults to interpret=True (this container is CPU-only),
-    # so the kernel row measures the Pallas *emulator*, not the MXU; on TPU
-    # pass functools.partial(tops.spmm, interpret=False) as tile_kernel.
+    # the kernel row runs the Pallas interpreter on the CPU and a compiled
+    # Mosaic kernel on a TPU; the label says which
+    mode = "interpreted" if interpret_mode() else "compiled"
     runners = {
         "global-pad scan": pipeline.PipelinedRunner(c, g, ts),
         "bucketed scan": pipeline.PipelinedRunner(c, g, bt),
-        "bucketed + pallas spmm (interpret)": pipeline.PipelinedRunner(
-            c, g, bt, tile_kernel=tops.spmm),
+        f"bucketed + pallas spmm ({mode}, {jax.default_backend()})":
+            pipeline.PipelinedRunner(c, g, bt, tile_kernel=tops.spmm),
     }
     wall_rows = []
     repeats = 1 if quick else 3
@@ -186,6 +189,8 @@ def bucketing_study(g, quick: bool = False):
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="2 models + 1 wall-clock repeat (CI bench-smoke)")

@@ -159,4 +159,6 @@ def run(quick: bool = False, mesh: str = "single"):
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+    compile_cache.enable()
     run()

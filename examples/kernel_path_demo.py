@@ -9,8 +9,8 @@ The TPU execution path for the paper's core dataflow (DESIGN.md §2):
   4. one `tile_spmm_pallas` call aggregates every tile into its destination
      partition — the Pallas grid is the inter-tile pipeline,
   5. same for GAT's edge softmax via the single-pass online-softmax kernel.
-Both are validated against the whole-graph oracle here (interpret mode —
-this container is CPU-only; on TPU pass interpret=False).
+Both are validated against the whole-graph oracle.  The kernels compile to
+Mosaic on a TPU and run in the Pallas interpreter on the CPU.
 """
 import pathlib
 import sys
@@ -25,6 +25,7 @@ import numpy as np
 from repro.core import reorder, tiling
 from repro.gnn import graphs
 from repro.kernels.tile_spmm import ops as tops
+from repro.kernels.tile_spmm.kernel import interpret_mode
 
 
 def main():
@@ -54,7 +55,9 @@ def main():
     t0 = time.time()
     out_parts = tops.spmm(adj, xsrc, pid, flags, n_parts=tiles.n_dst_parts)
     out_parts = jax.block_until_ready(out_parts)
-    print(f"tile_spmm_pallas (interpret): {time.time()-t0:.2f}s "
+    mode = "interpreted" if interpret_mode() else "compiled"
+    print(f"tile_spmm_pallas ({mode}, {jax.default_backend()}): "
+          f"{time.time()-t0:.2f}s "
           f"-> {out_parts.shape}")
 
     # re-assemble (P, Dmax, F) -> (V, F), apply the dFunction (norm + relu)

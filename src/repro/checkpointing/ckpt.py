@@ -37,13 +37,12 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
-from ..jax_compat import tree_flatten_with_path
 
 _SEP = "/"
 
 
 def _flatten_with_paths(tree) -> List[Tuple[str, Any]]:
-    flat = tree_flatten_with_path(tree)[0]
+    flat = jax.tree.flatten_with_path(tree)[0]
     out = []
     for path, leaf in flat:
         key = _SEP.join(str(getattr(p, "key", getattr(p, "idx", getattr(p, "name", p))))

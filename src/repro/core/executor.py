@@ -32,6 +32,9 @@ from ..gnn.graphs import Graph
 Array = Any
 
 _NEG_INF = -1e30  # used instead of -inf so max-reduce stays NaN-free on empty segments
+#: f32 weights keep f32 accuracy on the TPU's MXU (its default is one bf16
+#: pass); the reference and every engine share these dots
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 # ---------------------------------------------------------------------------
@@ -40,14 +43,14 @@ _NEG_INF = -1e30  # used instead of -inf so max-reduce stays NaN-free on empty s
 
 def apply_compute(op: str, attrs: Dict, params: Dict[str, Array], args: Sequence[Array]) -> Array:
     if op == "matmul" or op == "gemv":
-        return args[0] @ params[attrs["weight"]]
+        return jnp.matmul(args[0], params[attrs["weight"]], precision=_HIGHEST)
     if op == "bias_add":
         return args[0] + params[attrs["weight"]]
     if op == "bmm_edge":
         x, et = args
         w = params[attrs["weight"]]  # (n_types, d_in, d_out)
         sel = w[et[..., 0].astype(jnp.int32)]
-        return jnp.einsum("ef,efo->eo", x, sel)
+        return jnp.einsum("ef,efo->eo", x, sel, precision=_HIGHEST)
     if op == "add":
         return args[0] + args[1]
     if op == "sub":

@@ -252,16 +252,23 @@ class PipelinedRunner:
         return (tas, kcs, ta0, kc0, _perm_operand(reordering))
 
     # ------------------------------------------------------------------ run
-    def __call__(self, inputs: Dict[str, Array], params: Dict[str, Array],
-                 operands: Optional[Tuple] = None) -> List[Array]:
+    def _args(self, inputs, params, operands) -> Tuple:
         if operands is None:
             if self._operands is None:
                 self._operands = self.bind(self.tiles, self.reordering)
             operands = self._operands
-        tas, kcs, ta0, kc0, perm = operands
-        return self._jitted({k: jnp.asarray(v) for k, v in inputs.items()},
-                            {k: jnp.asarray(v) for k, v in params.items()},
-                            tas, kcs, ta0, kc0, perm)
+        return ({k: jnp.asarray(v) for k, v in inputs.items()},
+                {k: jnp.asarray(v) for k, v in params.items()}) + operands
+
+    def __call__(self, inputs: Dict[str, Array], params: Dict[str, Array],
+                 operands: Optional[Tuple] = None) -> List[Array]:
+        return self._jitted(*self._args(inputs, params, operands))
+
+    def lower(self, inputs: Dict[str, Array], params: Dict[str, Array]):
+        """``jax.stages.Lowered`` of this runner's program on the
+        construction tiles (HLO inspection: which gather blocks became
+        Mosaic kernels)."""
+        return self._jitted.lower(*self._args(inputs, params, None))
 
     def run_with(self, tiles, inputs: Dict[str, Array],
                  params: Dict[str, Array], reordering=None) -> List[Array]:
@@ -846,8 +853,7 @@ class ShardedRunner:
             self.mesh = jax.sharding.Mesh(np.asarray(devices[:n_devices]),
                                           ("shards",))
         P = jax.sharding.PartitionSpec
-        from ..jax_compat import shard_map
-        self._jitted = jax.jit(shard_map(
+        self._jitted = jax.jit(jax.shard_map(
             self._run, mesh=self.mesh,
             in_specs=(P(), P(), P("shards"), P()), out_specs=P(),
             check_vma=False))
@@ -928,14 +934,20 @@ class ShardedRunner:
                 f"shard tile capacities changed: {caps} != {self.caps}")
         if reordering is not None and not reordering.is_identity:
             repl = dict(repl, order=reordering.order, rank=reordering.rank)
-        return (jax.tree_util.tree_map(jnp.asarray, ops),
-                jax.tree_util.tree_map(jnp.asarray, repl))
+        return self._place(ops, repl)
+
+    def _place(self, ops: Dict, repl: Dict) -> Tuple:
+        """Put each shard's operand slice on its own device and the
+        replicated tables on every device of the mesh."""
+        spec = jax.sharding.PartitionSpec
+        return (jax.device_put(ops, jax.sharding.NamedSharding(
+                    self.mesh, spec("shards"))),
+                jax.device_put(repl, jax.sharding.NamedSharding(
+                    self.mesh, spec())))
 
     def _get_operands(self) -> Tuple:
         if self._operands is None:
-            self._operands = (
-                jax.tree_util.tree_map(jnp.asarray, self._ops_np),
-                jax.tree_util.tree_map(jnp.asarray, self._repl_np))
+            self._operands = self._place(self._ops_np, self._repl_np)
         return self._operands
 
     # ------------------------------------------------------------------ run
@@ -952,14 +964,18 @@ class ShardedRunner:
         compilation (no retrace: operand shapes identical by contract)."""
         return self(inputs, params, operands=self.bind(tiles, reordering))
 
+    def lower(self, inputs: Dict[str, Array], params: Dict[str, Array]):
+        """``jax.stages.Lowered`` of this runner's program on the
+        construction tiles."""
+        ops, repl = self._get_operands()
+        return self._jitted.lower(
+            {k: jnp.asarray(v) for k, v in inputs.items()},
+            {k: jnp.asarray(v) for k, v in params.items()}, ops, repl)
+
     def lower_text(self, inputs: Dict[str, Array],
                    params: Dict[str, Array]) -> str:
         """Compiled HLO text (collective-census hook for tests/benchmarks)."""
-        ops, repl = self._get_operands()
-        lowered = self._jitted.lower(
-            {k: jnp.asarray(v) for k, v in inputs.items()},
-            {k: jnp.asarray(v) for k, v in params.items()}, ops, repl)
-        return lowered.compile().as_text()
+        return self.lower(inputs, params).compile().as_text()
 
     # ---------------------------------------------------------- trace-time
     #: per-tile operand keys of the lax.scan body (kernel constants like

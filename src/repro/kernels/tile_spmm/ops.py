@@ -20,8 +20,6 @@ import numpy as np
 from ...core.tiling import BucketedTileSet, TileSet
 from .kernel import (segment_softmax_csr_pallas, segment_softmax_pallas,
                      tile_flags, tile_spmm_csr_pallas, tile_spmm_pallas)
-from .ref import (segment_softmax_csr_ref, segment_softmax_ref,
-                  tile_spmm_csr_ref, tile_spmm_ref)
 
 
 def densify_tiles(tiles: Union[TileSet, BucketedTileSet],
@@ -102,43 +100,14 @@ def densify_edge_scores(scores, edge_dst, n_edge, *, dmax: int):
     return jax.vmap(per_tile)(s, edge_dst)
 
 
-@functools.partial(jax.jit, static_argnames=("n_parts", "use_pallas", "interpret"))
-def spmm(adj, xsrc, part_id, flags, *, n_parts: int, use_pallas: bool = True,
-         interpret: bool = True):
-    if use_pallas:
-        return tile_spmm_pallas(adj, xsrc, part_id, flags, n_parts=n_parts,
-                                interpret=interpret)
-    return tile_spmm_ref(adj, xsrc, part_id, n_parts)
+# Kernel entry points.  The backend decides the Pallas mode
+# (:func:`~repro.kernels.tile_spmm.kernel.interpret_mode`); the ``*_ref``
+# oracles in ``ref.py`` are for tests only.
+spmm = jax.jit(tile_spmm_pallas, static_argnames=("n_parts",))
+gat_aggregate = jax.jit(segment_softmax_pallas, static_argnames=("n_parts",))
 
-
-@functools.partial(jax.jit, static_argnames=("n_parts", "use_pallas", "interpret"))
-def gat_aggregate(scores, vals, part_id, flags, *, n_parts: int,
-                  use_pallas: bool = True, interpret: bool = True):
-    if use_pallas:
-        return segment_softmax_pallas(scores, vals, part_id, flags,
-                                      n_parts=n_parts, interpret=interpret)
-    return segment_softmax_ref(scores, vals, part_id, n_parts)
-
-
-# ---------------------------------------------------------------------------
 # CSR-within-tile entry points: no densify pass — ``col`` IS the CSR-ordered
 # ``edge_src`` and weights/scores stay per-edge vectors.
-# ---------------------------------------------------------------------------
-
-@functools.partial(jax.jit, static_argnames=("n_parts", "use_pallas", "interpret"))
-def spmm_csr(row_ptr, col, w, xsrc, part_id, flags, *, n_parts: int,
-             use_pallas: bool = True, interpret: bool = True):
-    if use_pallas:
-        return tile_spmm_csr_pallas(row_ptr, col, w, xsrc, part_id, flags,
-                                    n_parts=n_parts, interpret=interpret)
-    return tile_spmm_csr_ref(row_ptr, col, w, xsrc, part_id, n_parts)
-
-
-@functools.partial(jax.jit, static_argnames=("n_parts", "use_pallas", "interpret"))
-def gat_aggregate_csr(row_ptr, scores, vals, part_id, flags, *, n_parts: int,
-                      use_pallas: bool = True, interpret: bool = True):
-    if use_pallas:
-        return segment_softmax_csr_pallas(row_ptr, scores, vals, part_id,
-                                          flags, n_parts=n_parts,
-                                          interpret=interpret)
-    return segment_softmax_csr_ref(row_ptr, scores, vals, part_id, n_parts)
+spmm_csr = jax.jit(tile_spmm_csr_pallas, static_argnames=("n_parts",))
+gat_aggregate_csr = jax.jit(segment_softmax_csr_pallas,
+                            static_argnames=("n_parts",))

@@ -6,7 +6,10 @@ X (Smax, F) — the reference accumulates  out[p] = sum_{tiles t of p} A_t X_t.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
+
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def tile_spmm_ref(adj, xsrc, part_id, n_parts: int):
@@ -15,7 +18,7 @@ def tile_spmm_ref(adj, xsrc, part_id, n_parts: int):
     F = xsrc.shape[-1]
     out = jnp.zeros((n_parts, D, F), jnp.float32)
     contrib = jnp.einsum("tds,tsf->tdf", adj.astype(jnp.float32),
-                         xsrc.astype(jnp.float32))
+                         xsrc.astype(jnp.float32), precision=_HIGHEST)
     return out.at[part_id].add(contrib)
 
 
@@ -35,7 +38,7 @@ def tile_spmm_csr_ref(row_ptr, col, w, xsrc, part_id, n_parts: int):
     gathered = w[..., None].astype(jnp.float32) * \
         jnp.take_along_axis(xsrc.astype(jnp.float32), col[..., None], axis=1)
     sel = _csr_select(row_ptr, E).astype(jnp.float32)       # (T, D, E)
-    contrib = jnp.einsum("tde,tef->tdf", sel, gathered)
+    contrib = jnp.einsum("tde,tef->tdf", sel, gathered, precision=_HIGHEST)
     return jnp.zeros((n_parts, D, F), jnp.float32).at[part_id].add(contrib)
 
 
@@ -53,7 +56,8 @@ def segment_softmax_csr_ref(row_ptr, scores, vals, part_id, n_parts: int):
     p = jnp.where(sel, p, 0.0)
     l = jnp.zeros((n_parts, D)).at[part_id].add(p.sum(-1))
     acc = jnp.zeros((n_parts, D, F)).at[part_id].add(
-        jnp.einsum("tde,tef->tdf", p, vals.astype(jnp.float32)))
+        jnp.einsum("tde,tef->tdf", p, vals.astype(jnp.float32),
+                   precision=_HIGHEST))
     return acc / jnp.maximum(l, 1e-30)[..., None]
 
 
@@ -74,5 +78,6 @@ def segment_softmax_ref(scores, vals, part_id, n_parts: int):
     p = jnp.where(s > neg / 2, p, 0.0)
     l = jnp.zeros((n_parts, D)).at[part_id].add(p.sum(-1))
     acc = jnp.zeros((n_parts, D, F)).at[part_id].add(
-        jnp.einsum("tds,tsf->tdf", p, vals.astype(jnp.float32)))
+        jnp.einsum("tds,tsf->tdf", p, vals.astype(jnp.float32),
+                   precision=_HIGHEST))
     return acc / jnp.maximum(l, 1e-30)[..., None]

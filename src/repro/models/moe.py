@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..configs.base import ArchConfig
-from ..jax_compat import shard_map
 from ..kernels.moe_dispatch import ops as moe_ops
 from .common import DP, leaf
 
@@ -133,7 +132,7 @@ def moe_layer(cfg: ArchConfig, p: Dict, x: Array, *, mesh,
                               wg, wu, wd, n_data=n_data, capacity=cap,
                               axis_data=axis_data, axis_model="model")
 
-        fn = shard_map(
+        fn = jax.shard_map(
             device_fn, mesh=mesh,
             in_specs=(P(dp_spec, None),               # tokens over data axes
                       P(None, None),                  # router (replicated)

@@ -86,8 +86,8 @@ def test_grid_tile_rejects_unknown_layout():
 # CSR kernels vs whole-graph oracles (padding poisoned on purpose)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("use_pallas", [False, True], ids=["ref", "pallas"])
-def test_csr_spmm_matches_whole_graph(use_pallas, rng):
+@pytest.mark.parametrize("use_kernel", [False, True], ids=["ref", "pallas"])
+def test_csr_spmm_matches_whole_graph(use_kernel, rng):
     g = _graph(100, 420, seed=5)
     cs = tiling.grid_tile(g, 4, 3, sparse=True, layout="csr")
     F = 16
@@ -98,10 +98,13 @@ def test_csr_spmm_matches_whole_graph(use_pallas, rng):
     w = w_g[cs.edge_gid].astype(np.float32)
     for t in range(cs.n_tiles):                 # poison padded edge slots:
         w[t, int(cs.n_edge[t]):] = 1e9          # row_ptr must never reach them
-    out = tops.spmm_csr(jnp.asarray(cs.row_ptr), jnp.asarray(cs.edge_src),
-                        jnp.asarray(w), xs, jnp.asarray(cs.part_id),
-                        jnp.asarray(tile_flags(cs.part_id)),
-                        n_parts=cs.n_dst_parts, use_pallas=use_pallas)
+    args = (jnp.asarray(cs.row_ptr), jnp.asarray(cs.edge_src),
+            jnp.asarray(w), xs, jnp.asarray(cs.part_id))
+    if use_kernel:
+        out = tops.spmm_csr(*args, jnp.asarray(tile_flags(cs.part_id)),
+                            n_parts=cs.n_dst_parts)
+    else:
+        out = tile_spmm_csr_ref(*args, cs.n_dst_parts)
 
     whole = np.zeros((g.n_vertices, F), np.float32)
     np.add.at(whole, g.dst, w_g[:, None] * x[g.src])
@@ -111,8 +114,8 @@ def test_csr_spmm_matches_whole_graph(use_pallas, rng):
                                    atol=1e-4, rtol=1e-4)
 
 
-@pytest.mark.parametrize("use_pallas", [False, True], ids=["ref", "pallas"])
-def test_csr_segment_softmax_matches_whole_graph(use_pallas, rng):
+@pytest.mark.parametrize("use_kernel", [False, True], ids=["ref", "pallas"])
+def test_csr_segment_softmax_matches_whole_graph(use_kernel, rng):
     g = _graph(80, 360, seed=7)
     cs = tiling.grid_tile(g, 3, 3, sparse=True, layout="csr")
     F = 8
@@ -123,10 +126,14 @@ def test_csr_segment_softmax_matches_whole_graph(use_pallas, rng):
     vals = v_g[cs.edge_gid].astype(np.float32)
     for t in range(cs.n_tiles):
         scores[t, int(cs.n_edge[t]):] = 1e9     # poisoned padding again
-    out = tops.gat_aggregate_csr(
-        jnp.asarray(cs.row_ptr), jnp.asarray(scores), jnp.asarray(vals),
-        jnp.asarray(cs.part_id), jnp.asarray(tile_flags(cs.part_id)),
-        n_parts=cs.n_dst_parts, use_pallas=use_pallas)
+    args = (jnp.asarray(cs.row_ptr), jnp.asarray(scores), jnp.asarray(vals),
+            jnp.asarray(cs.part_id))
+    if use_kernel:
+        out = tops.gat_aggregate_csr(*args,
+                                     jnp.asarray(tile_flags(cs.part_id)),
+                                     n_parts=cs.n_dst_parts)
+    else:
+        out = segment_softmax_csr_ref(*args, cs.n_dst_parts)
 
     whole = np.zeros((g.n_vertices, F), np.float32)
     for v in np.unique(g.dst):
@@ -142,8 +149,8 @@ def test_csr_segment_softmax_matches_whole_graph(use_pallas, rng):
 
 
 def test_csr_refs_agree_with_each_other(rng):
-    """The within-layout oracles used by the dispatch fallback agree with
-    the kernel entry points on a bucketed batch."""
+    """The within-layout oracles agree with the kernel entry points on a
+    bucketed batch."""
     g = _graph(90, 380, seed=11)
     ts, _ = tiling.build_tiles(g, 4, 4, reorder="degree", layout="csr",
                                n_buckets=2)
