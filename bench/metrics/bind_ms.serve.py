@@ -1,0 +1,9 @@
+"""Host milliseconds per completed request in the serving engine's
+``serve.bind`` spans: tile arrays and kernel constants built and put on the
+device (``bench/spans.py``), over the traced stretch (program span)."""
+from bench import spans
+
+
+def read(run):
+    """The metric's value, or ``None`` where the run has nothing to read."""
+    return spans.host_ms(run, ("serve.bind",))

@@ -77,6 +77,14 @@ def _perm_operand(reordering) -> Optional[Dict[str, Array]]:
                 rank=jnp.asarray(reordering.rank))
 
 
+def _stage(name: str):
+    """Named scope of one stage of the scheduled program (``zipper.vertex``,
+    ``zipper.edge``, ``zipper.densify``, ``zipper.kernel``).  It only tags
+    the ops' ``op_name`` metadata, so a device trace can split the
+    program's time by stage; the ops themselves do not change."""
+    return jax.named_scope(f"zipper.{name}")
+
+
 def _check_reorder_mode(expected: str, reordering) -> None:
     mode = "identity" if reordering is None else reordering.mode
     if mode != expected:
@@ -284,17 +292,20 @@ class PipelinedRunner:
         sp = self.sp
         V = self.graph.n_vertices
         P, dmax = self.tiles.n_dst_parts, self.dmax
-        pad_ids = jnp.asarray(self.part_ids_pad)          # (P, Dmax), V = invalid
-        pad_valid = (pad_ids < V)[..., None]              # (P, Dmax, 1)
-        safe_pad_ids = jnp.minimum(pad_ids, V - 1)
+        # every op below is opened under one stage scope (``_stage``)
+        with _stage("vertex"):
+            pad_ids = jnp.asarray(self.part_ids_pad)      # (P, Dmax), V = invalid
+            pad_valid = (pad_ids < V)[..., None]          # (P, Dmax, 1)
+            safe_pad_ids = jnp.minimum(pad_ids, V - 1)
 
-        if perm is not None:
-            # requests arrive in original vertex order; the tiles (and edge
-            # arrays, which degree_sort leaves in place) live in reordered
-            # space — permute vertex features in, outputs back at the end
-            inputs = dict(inputs)
-            for name in {name for _, name in sp.vertex_inputs}:
-                inputs[name] = inputs[name][perm["order"]]
+            if perm is not None:
+                # requests arrive in original vertex order; the tiles (and
+                # edge arrays, which degree_sort leaves in place) live in
+                # reordered space — permute vertex features in, outputs back
+                # at the end
+                inputs = dict(inputs)
+                for name in {name for _, name in sp.vertex_inputs}:
+                    inputs[name] = inputs[name][perm["order"]]
 
         vstore: Dict[int, Array] = {nid: inputs[name]
                                     for nid, name in sp.vertex_inputs}
@@ -395,94 +406,104 @@ class PipelinedRunner:
             # results of the previous phase are consumed directly in padded
             # layout — the drain of layer l fuses into layer l+1's dst work)
             if phase.dst.store_ids:
-                denv = eval_vertex(safe_pad_ids, phase.dst.nodes, padded=True)
-                for nid in phase.dst.store_ids:
-                    vstore[nid] = unpad(denv[nid])
+                with _stage("vertex"):
+                    denv = eval_vertex(safe_pad_ids, phase.dst.nodes,
+                                       padded=True)
+                    for nid in phase.dst.store_ids:
+                        vstore[nid] = unpad(denv[nid])
             if not phase.has_tile_work:
                 continue
 
             scan_gathers = phase.scan_gathers()
 
             # ---- accumulators (shared across all buckets of this phase)
-            acc = _init_gather_acc(scan_gathers, P, dmax)
+            with _stage("edge"):
+                acc = _init_gather_acc(scan_gathers, P, dmax)
 
             # ---- kernel-dispatched gather blocks
             for g in phase.kernel_gathers():
                 if g.kernel == S.KERNEL_SEGMENT_SOFTMAX:
-                    xs0 = with_dst(ta0)
-
                     def tile_se(xs):
                         senv = eval_vertex(xs["src_ids"], phase.src.nodes)
                         _, elookup = edge_env(g.edge_nodes, xs, senv)
                         h = src_value(senv, g.src_value_id, xs["src_ids"])
                         return elookup(g.score_id)[:, 0], h[xs["edge_src"]]
 
-                    scores_e, vals = jax.vmap(tile_se)(xs0)    # (T,E), (T,E,F)
+                    with _stage("edge"):
+                        scores_e, vals = jax.vmap(tile_se)(with_dst(ta0))
                     if self.layout == "csr":
                         # per-edge scores/vals feed the kernel directly: the
                         # row-pointer walk replaces the densify pass
-                        out = self.softmax_csr_kernel(
-                            ta0["row_ptr"], scores_e, vals, ta0["part_id"],
-                            kc0["flags"], n_parts=P)
+                        with _stage("kernel"):
+                            out = self.softmax_csr_kernel(
+                                ta0["row_ptr"], scores_e, vals, ta0["part_id"],
+                                kc0["flags"], n_parts=P)
                     else:
-                        scores = densify_edge_scores(
-                            scores_e, ta0["edge_dst"], ta0["n_edge"], dmax=dmax)
-                        out = self.softmax_kernel(scores, vals, ta0["part_id"],
-                                                  kc0["flags"], n_parts=P)
-                    out = jnp.where(kc0["pmask"][:, None, None] > 0, out, 0.0)
-                    publish_gather(g.acc.recv_id, out)
+                        with _stage("densify"):
+                            scores = densify_edge_scores(
+                                scores_e, ta0["edge_dst"], ta0["n_edge"],
+                                dmax=dmax)
+                        with _stage("kernel"):
+                            out = self.softmax_kernel(
+                                scores, vals, ta0["part_id"], kc0["flags"],
+                                n_parts=P)
+                    with _stage("vertex"):
+                        out = jnp.where(kc0["pmask"][:, None, None] > 0, out,
+                                        0.0)
+                        publish_gather(g.acc.recv_id, out)
                     continue
 
                 # SpMM variants: one densified kernel call per size bucket,
                 # partition outputs summed into a shared (P, Dmax, F) buffer
-                total = jnp.zeros((P, dmax, g.acc.dim), jnp.float32)
+                with _stage("vertex"):
+                    total = jnp.zeros((P, dmax, g.acc.dim), jnp.float32)
                 for ta, kc in zip(tas, kcs):
-                    senv = eval_vertex(ta["src_ids"], phase.src.nodes)
-                    xsrc = src_value(senv, g.src_value_id, ta["src_ids"])
-                    if self.layout == "csr":
+                    def tile_w(xs):
+                        senv_t = eval_vertex(xs["src_ids"], phase.src.nodes)
+                        _, elookup = edge_env(g.edge_nodes, xs, senv_t)
+                        return elookup(g.weight_id)[:, 0]
+
+                    with _stage("edge"):
+                        senv = eval_vertex(ta["src_ids"], phase.src.nodes)
+                        xsrc = src_value(senv, g.src_value_id, ta["src_ids"])
                         if g.kernel == S.KERNEL_SPMM:
-                            w = jnp.ones(ta["edge_src"].shape, jnp.float32)
+                            w = None
                         else:
-                            xs_b = with_dst(ta)
-
-                            def tile_w(xs):
-                                senv_t = eval_vertex(xs["src_ids"],
-                                                     phase.src.nodes)
-                                _, elookup = edge_env(g.edge_nodes, xs, senv_t)
-                                return elookup(g.weight_id)[:, 0]
-
-                            w = jax.vmap(tile_w)(xs_b)         # (T, E)
-                            # zero padded slots: they are unreachable via the
-                            # row pointers but must not inject inf/NaN
-                            emask = (jnp.arange(w.shape[1])[None, :]
-                                     < ta["n_edge"][:, None])
-                            w = jnp.where(emask, w, 0.0)
-                        out = self.csr_kernel(ta["row_ptr"], ta["edge_src"],
-                                              w, xsrc, ta["part_id"],
-                                              kc["flags"], n_parts=P)
+                            w = jax.vmap(tile_w)(with_dst(ta))     # (T, E)
+                    if self.layout == "csr":
+                        with _stage("edge"):
+                            if w is None:
+                                w = jnp.ones(ta["edge_src"].shape, jnp.float32)
+                            else:
+                                # zero padded slots: they are unreachable via
+                                # the row pointers but must not inject inf/NaN
+                                emask = (jnp.arange(w.shape[1])[None, :]
+                                         < ta["n_edge"][:, None])
+                                w = jnp.where(emask, w, 0.0)
+                        with _stage("kernel"):
+                            out = self.csr_kernel(ta["row_ptr"],
+                                                  ta["edge_src"], w, xsrc,
+                                                  ta["part_id"], kc["flags"],
+                                                  n_parts=P)
                     else:
-                        if g.kernel == S.KERNEL_SPMM:
+                        if w is None:
                             adj = kc["adj"]
                         else:    # weighted: densify the runtime edge weights
-                            xs_b = with_dst(ta)
-
-                            def tile_w(xs):
-                                senv_t = eval_vertex(xs["src_ids"],
-                                                     phase.src.nodes)
-                                _, elookup = edge_env(g.edge_nodes, xs, senv_t)
-                                return elookup(g.weight_id)[:, 0]
-
-                            w = jax.vmap(tile_w)(xs_b)         # (T, E)
-                            adj = densify_edge_weights(
-                                w, ta["edge_dst"], ta["edge_src"], ta["n_edge"],
-                                dmax=dmax, smax=int(ta["src_ids"].shape[1]))
-                        out = self.tile_kernel(adj, xsrc, ta["part_id"],
-                                               kc["flags"], n_parts=P)
+                            with _stage("densify"):
+                                adj = densify_edge_weights(
+                                    w, ta["edge_dst"], ta["edge_src"],
+                                    ta["n_edge"], dmax=dmax,
+                                    smax=int(ta["src_ids"].shape[1]))
+                        with _stage("kernel"):
+                            out = self.tile_kernel(adj, xsrc, ta["part_id"],
+                                                   kc["flags"], n_parts=P)
                     # partitions with no tile in this bucket are never
                     # written by the kernel (uninitialized, may be NaN)
-                    total = total + jnp.where(kc["pmask"][:, None, None] > 0,
-                                              out, 0.0)
-                publish_gather(g.acc.recv_id, total)
+                    with _stage("vertex"):
+                        total = total + jnp.where(
+                            kc["pmask"][:, None, None] > 0, out, 0.0)
+                with _stage("vertex"):
+                    publish_gather(g.acc.recv_id, total)
 
             # ---- the pipelined tile loop, one scan per bucket
             if scan_gathers:
@@ -498,17 +519,21 @@ class PipelinedRunner:
                                            emask, edst, pid, dmax)
                     return acc, 0
 
-                for ta in tas:
-                    acc, _ = jax.lax.scan(body, acc, with_dst(ta))
+                with _stage("edge"):
+                    for ta in tas:
+                        acc, _ = jax.lax.scan(body, acc, with_dst(ta))
 
                 # ---- publish scan-gather results (padded layout; flat (V,)
                 # store only when a tile-side path reads them)
-                for g in scan_gathers:
-                    publish_gather(g.acc.recv_id, _drain_gather_acc(acc, g))
+                with _stage("vertex"):
+                    for g in scan_gathers:
+                        publish_gather(g.acc.recv_id,
+                                       _drain_gather_acc(acc, g))
 
-        outs = [vstore[o] for o in sp.outputs]
-        if perm is not None:
-            outs = [o[perm["rank"]] for o in outs]
+        with _stage("vertex"):
+            outs = [vstore[o] for o in sp.outputs]
+            if perm is not None:
+                outs = [o[perm["rank"]] for o in outs]
         return outs
 
 

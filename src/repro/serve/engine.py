@@ -10,18 +10,25 @@ ScheduledProgram execution per size class:
    (:class:`~repro.serve.signature.ShapeRegistry`);
 3. the structural signature keys the :class:`~repro.serve.cache.ProgramCache`
    — a hit reuses a warm jitted :class:`~repro.core.pipeline.PipelinedRunner`
-   via ``run_with`` (rebind tile operands, no retrace, no recompile);
+   (``bind`` the tile operands, then call it: no retrace, no recompile);
 4. merged outputs are sliced back into per-graph arrays.
+
+Each stage runs inside a ``jax.profiler.TraceAnnotation`` span named
+``serve.<stage>`` that carries the request's id (docs/SERVING.md,
+"Tracing"); with no trace running a span costs about a microsecond.
 
 Request padding is pure overhead the quantization keeps bounded (< 2x rows
 worst case); compilation cost is amortized across every request of a class.
 """
 from __future__ import annotations
 
+import itertools
 import threading
 from typing import Dict, List, Optional, Sequence, Union
 
+import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..core import compiler as C
 from ..core import schedule as S
@@ -34,6 +41,17 @@ from .signature import (ShapeRegistry, quantize, size_class,
                         structure_signature)
 
 Array = np.ndarray
+
+#: process-wide request ids: the ``request`` argument of every serving span,
+#: which ties a request's spans together across threads and engines
+_REQUEST_IDS = itertools.count()
+
+
+def _count_h2d(span: TraceAnnotation, arrays) -> None:
+    """Put the number and bytes of ``arrays`` (what a stage hands to the
+    device) on ``span``."""
+    span.set_metadata(arrays=len(arrays),
+                      bytes=int(sum(a.nbytes for a in arrays)))
 
 
 def _pad_rows(arr: Array, rows: int) -> Array:
@@ -194,16 +212,21 @@ class InferenceServer:
         if params is None:
             raise ValueError("no params bound to the server or the request")
 
-        groups: Dict[tuple, List[int]] = {}
-        for i, g in enumerate(graphs):
-            groups.setdefault(size_class(g), []).append(i)
+        rid = next(_REQUEST_IDS)
+        with TraceAnnotation("serve.submit", request=rid,
+                             graphs=len(graphs)) as span:
+            with TraceAnnotation("serve.group", request=rid):
+                groups: Dict[tuple, List[int]] = {}
+                for i, g in enumerate(graphs):
+                    groups.setdefault(size_class(g), []).append(i)
+            span.set_metadata(groups=len(groups))
 
-        results: List[Optional[List[Array]]] = [None] * len(graphs)
-        for idxs in groups.values():
-            outs = self._run_group([graphs[i] for i in idxs],
-                                   [inputs[i] for i in idxs], params)
-            for i, out in zip(idxs, outs):
-                results[i] = out
+            results: List[Optional[List[Array]]] = [None] * len(graphs)
+            for idxs in groups.values():
+                outs = self._run_group([graphs[i] for i in idxs],
+                                       [inputs[i] for i in idxs], params, rid)
+                for i, out in zip(idxs, outs):
+                    results[i] = out
         with self._stats_lock:
             self._requests += 1
             self._graphs_served += len(graphs)
@@ -237,9 +260,51 @@ class InferenceServer:
     # ------------------------------------------------------------ internals
     def _run_group(self, graphs: List[Graph],
                    inputs: List[Dict[str, Array]],
-                   params: Dict[str, Array]) -> List[List[Array]]:
-        batch = batch_graphs(graphs)
-        V_real = batch.graph.n_vertices
+                   params: Dict[str, Array], rid: int) -> List[List[Array]]:
+        with TraceAnnotation("serve.run_group", request=rid,
+                             graphs=len(graphs)):
+            with TraceAnnotation("serve.merge", request=rid):
+                batch = batch_graphs(graphs)
+            with TraceAnnotation("serve.canonical", request=rid):
+                V_pad, tiles, E_pad, ro, tuned, tuned_key = \
+                    self._canonical(graphs, batch)
+            with TraceAnnotation("serve.inputs", request=rid):
+                sp = self.compiled.schedule(self.kernel_dispatch)
+                merged_inputs: Dict[str, Array] = {}
+                for _, name in sp.vertex_inputs:
+                    merged_inputs[name] = _pad_rows(np.concatenate(
+                        [np.asarray(inp[name]) for inp in inputs]), V_pad)
+                for _, name in sp.edge_inputs:
+                    merged_inputs[name] = _pad_rows(np.concatenate(
+                        [np.asarray(inp[name]) for inp in inputs]), E_pad)
+            with TraceAnnotation("serve.lookup", request=rid) as span:
+                runner, hit = self._runner(tiles, E_pad, ro, tuned,
+                                           tuned_key, V_pad)
+                span.set_metadata(hit=int(hit))
+            with TraceAnnotation("serve.bind", request=rid) as span:
+                operands = runner.bind(tiles, reordering=ro)
+                if span.is_enabled():
+                    _count_h2d(span, jax.tree_util.tree_leaves(operands))
+            with TraceAnnotation("serve.dispatch", request=rid) as span:
+                if span.is_enabled():
+                    _count_h2d(span, [a for a in (*merged_inputs.values(),
+                                                  *params.values())
+                                      if isinstance(a, np.ndarray)])
+                outs = runner(merged_inputs, params, operands)
+            with self._stats_lock:
+                self._batches_run += 1
+
+            with TraceAnnotation("serve.fetch", request=rid):
+                V_real = batch.graph.n_vertices
+                per_output = [batch.unbatch_vertex(np.asarray(o)[:V_real])
+                              for o in outs]
+                return [[per_output[o][g] for o in range(len(per_output))]
+                        for g in range(len(graphs))]
+
+    def _canonical(self, graphs: List[Graph], batch):
+        """The class's padded vertex count, canonical tiles, padded edge
+        rows, vertex reordering, and tuned config and key (``None`` and
+        ``()`` on the default route)."""
         # class keys carry the program identity (name + layer count): shape
         # registrations of a 1-layer and a 2-layer program of the same model
         # must never alias, even if two servers share a registry
@@ -265,23 +330,20 @@ class InferenceServer:
             tuned_key = ()
             merged_graph, tiles, E_pad, ro = self.shapes.canonical(
                 class_key, batch.graph)
-        V_pad = merged_graph.n_vertices
+        return merged_graph.n_vertices, tiles, E_pad, ro, tuned, tuned_key
 
-        sp = self.compiled.schedule(self.kernel_dispatch)
-        merged_inputs: Dict[str, Array] = {}
-        for _, name in sp.vertex_inputs:
-            merged_inputs[name] = _pad_rows(
-                np.concatenate([np.asarray(inp[name]) for inp in inputs]), V_pad)
-        for _, name in sp.edge_inputs:
-            merged_inputs[name] = _pad_rows(
-                np.concatenate([np.asarray(inp[name]) for inp in inputs]), E_pad)
-
+    def _runner(self, tiles, E_pad: int, ro, tuned, tuned_key, V_pad: int):
+        """(runner, hit): the cached runner of the structure signature, built
+        on a miss."""
         n_dev = (self.shard_devices
                  if self.shard_devices and self.shard_devices > 1
                  and V_pad >= self.shard_min_vertices else 1)
         if tuned is not None and n_dev > 1:
             # the tuned shard count caps (never raises) the mesh size
             n_dev = max(1, min(n_dev, tuned.n_shards))
+        key = structure_signature(self.compiled, tiles, E_pad,
+                                  self.kernel_dispatch, reorder=ro.mode)
+        built = []
         if n_dev > 1:
             # sharded route over an n_dev mesh, kernel dispatch honored
             # inside shard_map; key carries the mesh size, the realized
@@ -289,40 +351,32 @@ class InferenceServer:
             # tuned config.  The runner holds the graph/tiles in reordered
             # vertex space; requests stay in original ids and the rebind
             # ships the permutation as a replicated traced operand.
-            key = structure_signature(self.compiled, tiles, E_pad,
-                                      self.kernel_dispatch,
-                                      reorder=ro.mode) + (
-                shard_layout_signature(tiles, n_dev, mode="contiguous",
-                                       quantize_tile_cap=True,
+            key += (shard_layout_signature(
+                        tiles, n_dev, mode="contiguous",
+                        quantize_tile_cap=True,
+                        kernel_dispatch=self.kernel_dispatch,
+                        kernels=self._kernel_tags,
+                        model_axis=self.shard_model_axis),
+                    tuned_key)
+
+            def build():
+                built.append(True)
+                return ShardedRunner(self.compiled, ro.graph, tiles, n_dev,
+                                     mode="contiguous", quantize_tile_cap=True,
+                                     kernel_dispatch=self.kernel_dispatch,
+                                     reordering=ro,
+                                     model_axis=self.shard_model_axis)
+        else:
+            key += (tuned_key,)
+
+            def build():
+                built.append(True)
+                return PipelinedRunner(self.compiled, ro.graph, tiles,
                                        kernel_dispatch=self.kernel_dispatch,
-                                       kernels=self._kernel_tags,
-                                       model_axis=self.shard_model_axis),
-                tuned_key)
-            runner = self.cache.get_or_build(
-                key, lambda: ShardedRunner(self.compiled, ro.graph, tiles,
-                                           n_dev, mode="contiguous",
-                                           quantize_tile_cap=True,
-                                           kernel_dispatch=self.kernel_dispatch,
-                                           reordering=ro,
-                                           model_axis=self.shard_model_axis),
-                owner=self.cache_owner)
+                                       donate_inputs=self.donate_inputs,
+                                       reordering=ro)
+        runner = self.cache.get_or_build(key, build, owner=self.cache_owner)
+        if n_dev > 1:
             with self._stats_lock:
                 self._sharded_batches += 1
-        else:
-            key = structure_signature(self.compiled, tiles, E_pad,
-                                      self.kernel_dispatch,
-                                      reorder=ro.mode) + (tuned_key,)
-            runner = self.cache.get_or_build(
-                key, lambda: PipelinedRunner(self.compiled, ro.graph, tiles,
-                                             kernel_dispatch=self.kernel_dispatch,
-                                             donate_inputs=self.donate_inputs,
-                                             reordering=ro),
-                owner=self.cache_owner)
-        outs = runner.run_with(tiles, merged_inputs, params, reordering=ro)
-        with self._stats_lock:
-            self._batches_run += 1
-
-        per_output = [batch.unbatch_vertex(np.asarray(o)[:V_real])
-                      for o in outs]
-        return [[per_output[o][g] for o in range(len(per_output))]
-                for g in range(len(graphs))]
+        return runner, not built
