@@ -23,6 +23,12 @@ derives no levels or roles of its own.  Per phase:
 * ``scan``-tagged gathers run the pipelined ``lax.scan`` tile loop, one scan
   per bucket with shared accumulators.
 
+A weighted-SpMM block over COO tiles whose edge weight reads only its
+edge's endpoint vertex values (GCN's ``dn[src]·dn[dst]``) evaluates that
+weight once on the dense ``(T, Dmax, Smax)`` tile grid, a destination
+column broadcast against a source row, and scales the tile's edge-count
+adjacency by it, instead of gathering endpoint values per padded edge slot.
+
 ``tiles`` may be a :class:`~repro.core.tiling.TileSet` (one global-pad
 bucket) or a :class:`~repro.core.tiling.BucketedTileSet`.
 """
@@ -35,6 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import compiler as C
+from . import ir as IR
 from . import schedule as S
 from .executor import apply_compute, _NEG_INF
 from .tiling import (BucketedTileSet, ShardPlan, TileSet, exchange_sets,
@@ -83,6 +90,26 @@ def _stage(name: str):
     the ops' ``op_name`` metadata, so a device trace can split the
     program's time by stage; the ops themselves do not change."""
     return jax.named_scope(f"zipper.{name}")
+
+
+#: elementwise ops without a parameter: a weight built from endpoint values
+#: with these alone is a function of (destination, source) vertex pairs
+_GRID_OPS = frozenset(IR.ELW_UNARY + IR.ELW_BINARY) - {"bias_add"}
+
+
+def _vertex_only_weight(g: S.GatherBlock) -> bool:
+    """Whether a weighted gather block's edge weight depends only on the
+    values at each edge's source and destination: its edge nodes are
+    ``recvSrc``/``recvDst`` and elementwise ops over earlier edge nodes,
+    so neither the weight nor any node it reads is an edge input (those
+    come in per ``edge_gid``)."""
+    seen = set()
+    for n in g.edge_nodes:
+        if n.op not in ("recvSrc", "recvDst") and (
+                n.op not in _GRID_OPS or not set(n.inputs) <= seen):
+            return False
+        seen.add(n.id)
+    return g.weight_id in seen
 
 
 def _check_reorder_mode(expected: str, reordering) -> None:
@@ -191,6 +218,16 @@ class PipelinedRunner:
                              else reordering.mode)
         self.part_ids_pad, self.dmax = _padded_partition_ids(tiles)
         self._kernels = {g.kernel for ph in self.sp.phases for g in ph.gathers}
+        weighted = [g for ph in self.sp.phases for g in ph.kernel_gathers()
+                    if g.kernel == S.KERNEL_SPMM_WEIGHTED]
+        # recv ids of the weighted blocks whose weight runs on the tile grid
+        self._grid_weights = frozenset(
+            g.acc.recv_id for g in weighted
+            if self.layout != "csr" and _vertex_only_weight(g))
+        #: weighted gather blocks per weight path: ``grid`` on the dense
+        #: (T, Dmax, Smax) tile grid, ``edge`` per padded edge slot
+        self.weight_paths = {"grid": len(self._grid_weights),
+                             "edge": len(weighted) - len(self._grid_weights)}
         self._signature = (self.sp.structure_signature(),
                            tiles.shape_signature(), self.reorder_mode)
         self._operands: Optional[Tuple] = None   # lazy bind of ctor tiles
@@ -394,6 +431,25 @@ class PipelinedRunner:
         def src_value(senv, nid, rows):
             return senv[nid] if nid in senv else vstore[nid][rows]
 
+        def weight_grid(g, senv, ta):
+            """A vertex-only edge weight on the dense (T, Dmax, Smax) tile
+            grid: ``recvSrc`` reads the tile's source rows (T, 1, Smax, d),
+            ``recvDst`` its partition's rows (T, Dmax, 1, d), and the
+            elementwise nodes broadcast them against each other."""
+            genv: Dict[int, Array] = {}
+            for n in g.edge_nodes:
+                if n.op == "recvSrc":
+                    rows = src_value(senv, sp.scatter_value_of[n.id],
+                                     ta["src_ids"])
+                    genv[n.id] = rows[:, None]
+                elif n.op == "recvDst":
+                    cols = vstore[sp.scatter_value_of[n.id]][safe_pad_ids]
+                    genv[n.id] = cols[ta["part_id"]][:, :, None]
+                else:
+                    genv[n.id] = apply_compute(n.op, n.attrs, params,
+                                               [genv[i] for i in n.inputs])
+            return genv[g.weight_id][..., 0]
+
         def unpad(val):
             """(P, Dmax, d) partition-padded -> (V, d) vertex store."""
             flat = jnp.where(pad_valid, val, 0.0).reshape(P * dmax, -1)
@@ -457,6 +513,7 @@ class PipelinedRunner:
                 # partition outputs summed into a shared (P, Dmax, F) buffer
                 with _stage("vertex"):
                     total = jnp.zeros((P, dmax, g.acc.dim), jnp.float32)
+                on_grid = g.acc.recv_id in self._grid_weights
                 for ta, kc in zip(tas, kcs):
                     def tile_w(xs):
                         senv_t = eval_vertex(xs["src_ids"], phase.src.nodes)
@@ -468,6 +525,8 @@ class PipelinedRunner:
                         xsrc = src_value(senv, g.src_value_id, ta["src_ids"])
                         if g.kernel == S.KERNEL_SPMM:
                             w = None
+                        elif on_grid:
+                            w = weight_grid(g, senv, ta)     # (T, Dmax, Smax)
                         else:
                             w = jax.vmap(tile_w)(with_dst(ta))     # (T, E)
                     if self.layout == "csr":
@@ -486,14 +545,24 @@ class PipelinedRunner:
                                                   ta["part_id"], kc["flags"],
                                                   n_parts=P)
                     else:
+                        smax = int(ta["src_ids"].shape[1])
                         if w is None:
                             adj = kc["adj"]
+                        elif on_grid:
+                            # cnt·w is the sum of cnt parallel edges' equal
+                            # weights; a select, since off-edge grid cells
+                            # may be inf or NaN and inf·0 is NaN
+                            with _stage("densify"):
+                                cnt = densify_edge_weights(
+                                    jnp.ones(ta["edge_src"].shape, jnp.float32),
+                                    ta["edge_dst"], ta["edge_src"],
+                                    ta["n_edge"], dmax=dmax, smax=smax)
+                                adj = jnp.where(cnt > 0, cnt * w, 0.0)
                         else:    # weighted: densify the runtime edge weights
                             with _stage("densify"):
                                 adj = densify_edge_weights(
                                     w, ta["edge_dst"], ta["edge_src"],
-                                    ta["n_edge"], dmax=dmax,
-                                    smax=int(ta["src_ids"].shape[1]))
+                                    ta["n_edge"], dmax=dmax, smax=smax)
                         with _stage("kernel"):
                             out = self.tile_kernel(adj, xsrc, ta["part_id"],
                                                    kc["flags"], n_parts=P)
