@@ -30,13 +30,12 @@ from repro.serve import AsyncInferenceServer, Overloaded
 
 def make_requests(model, n, *, v, e, seed0=0):
     """n (graph, inputs) pairs for one tenant, same size class."""
-    spec = models.MODELS[model]
     tr = models.trace_named(model)
     out = []
     for k in range(n):
         g = graphs.random_graph(
             v, e, seed=seed0 + k, model="powerlaw",
-            n_edge_types=spec.n_edge_types if spec.needs_etype else None)
+            n_edge_types=models.n_edge_types(tr))
         out.append((g, models.init_inputs(tr, g, seed=seed0 + k)))
     return out
 

@@ -34,7 +34,6 @@ def main(argv=None):
     if args.requests < 1 or args.batch < 1:
         ap.error("--requests and --batch must be >= 1")
 
-    spec = models.MODELS[args.model]
     tr = models.trace_named(args.model)
     compiled = compiler.compile_gnn(tr)
     params = models.init_params(tr)
@@ -48,7 +47,7 @@ def main(argv=None):
             seed = req * 1000 + k
             g = graphs.random_graph(
                 args.vertices, args.edges, seed=seed, model="powerlaw",
-                n_edge_types=spec.n_edge_types if spec.needs_etype else None)
+                n_edge_types=models.n_edge_types(tr))
             gs.append(g)
             ins.append(models.init_inputs(tr, g, seed=seed))
         t0 = time.perf_counter()

@@ -23,9 +23,9 @@ from .core import compiler, isa, tiling
 from .core.streams import HWConfig, build_task_graph
 from .gnn import graphs, models
 
-#: deterministic tile-set substrate for the task-graph analyses (--all)
-_GRAPH_SPEC = dict(n_vertices=150, n_edges=600, seed=3, model="powerlaw",
-                   n_edge_types=3)
+#: deterministic tile-set substrate for the task-graph analyses (--all);
+#: a typed model's graph takes the model's relation count
+_GRAPH_SPEC = dict(n_vertices=150, n_edges=600, seed=3, model="powerlaw")
 
 
 def _cell_name(name: str, n_layers: int) -> str:
@@ -38,10 +38,12 @@ def analyze_matrix(names: List[str], layer_counts: List[int], dim: int,
     cell title -> diagnostics (compile failures become ZA-coded errors
     via the raised VerificationError's own diagnostics)."""
     report: Dict[str, List[A.Diagnostic]] = {}
-    g = graphs.random_graph(**_GRAPH_SPEC) if with_task_graphs else None
     for name in names:
         for n_layers in layer_counts:
             tr = models.trace_stacked(name, n_layers, dim, dim, dim)
+            g = (graphs.random_graph(**_GRAPH_SPEC,
+                                     n_edge_types=models.n_edge_types(tr))
+                 if with_task_graphs else None)
             # verify=False: the CLI reports findings instead of raising
             c = compiler.compile_gnn(tr, verify=False)
             diags = A.verify_ir(c.ir)

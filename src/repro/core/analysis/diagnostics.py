@@ -51,6 +51,7 @@ CODES: Dict[str, tuple] = {
     "ZS109": (ERROR, "kernel-covered node still scheduled in a block"),
     "ZS110": (INFO, "missed kernel: gather fell back to the scan path"),
     "ZS111": (ERROR, "accumulator spec inconsistent with its send node"),
+    "ZS112": (ERROR, "pallas_relation preconditions not met by the IR"),
     # --- schedule hazards & exchange census (ZH2xx) -----------------------
     "ZH201": (ERROR, "drain-ordering race: read not ordered after producer"),
     "ZH202": (ERROR, "task dependency references an unknown/forward task"),

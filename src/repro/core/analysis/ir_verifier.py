@@ -59,6 +59,13 @@ def _check_dims(n: IR.IRNode, dims_in: List[int],
             err("ZA005", f"{n.op}: missing/short weight shape {wshape}")
             return out
         k, m = wshape[-2], wshape[-1]
+        if n.op == "bmm_edge":
+            # (n_types, n_blocks, k, m): block-diagonal, n_blocks * k wide
+            if len(wshape) != 4:
+                err("ZA005", f"bmm_edge: weight {wshape} is not "
+                             f"(n_types, n_blocks, in, out)")
+                return out
+            k, m = wshape[1] * k, wshape[1] * m
         if dims_in and dims_in[0] != k:
             err("ZA005", f"{n.op}: contraction dim {dims_in[0]} != "
                          f"weight {wshape}[-2]={k}")

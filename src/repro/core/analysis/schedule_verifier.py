@@ -207,11 +207,69 @@ def _check_softmax(g: S.GatherBlock, phase: S.Phase, ctx: _Ctx,
     return None
 
 
+def _walk_typed(send: IR.IRNode, ctx: _Ctx
+                ) -> Tuple[Optional[Dict], Optional[str]]:
+    """Re-derive the typed-aggregation chain ``bmm_edge(recvSrc, etype)
+    [* per-edge scalar] -> sendDstSum`` from its send; ``(derived, None)``
+    or ``(None, reason)`` naming the first broken link."""
+    nodes, only = ctx.nodes, ctx.only_consumer
+    if send.op != "sendDstSum":
+        return None, f"send is {send.op}, needs sendDstSum"
+    val = nodes.get(send.inputs[0])
+    if val is None:
+        return None, f"gather operand %{send.inputs[0]} missing from the IR"
+    if only(val.id) is not send:
+        return None, f"message %{val.id} is shared beyond the gather"
+    covered = {val.id, send.id}
+    weight = None
+    bmm = val
+    if val.op == "mul":
+        a, b = (nodes[i] for i in val.inputs)
+        bmm, weight = (a, b) if a.op == "bmm_edge" else (b, a)
+        if weight.dim != 1 or weight.is_recv():
+            return None, (f"weight operand %{weight.id} ({weight.op} dim="
+                          f"{weight.dim}) is not a per-edge scalar")
+        if only(bmm.id) is not val:
+            return None, f"bmm_edge %{bmm.id} is shared beyond the message"
+        covered.add(bmm.id)
+    if bmm.op != "bmm_edge":
+        return None, f"message is {bmm.op}, needs bmm_edge"
+    rs, et = (nodes[i] for i in bmm.inputs)
+    if rs.op != "recvSrc" or only(rs.id) is not bmm:
+        return None, f"bmm_edge operand is {rs.op}, needs a private recvSrc"
+    if et.op != "input":
+        return None, (f"edge type %{et.id} is a {et.op} — the relation "
+                      f"layout needs an edge input")
+    covered.add(rs.id)
+    return {"covered": covered, "bmm_id": bmm.id,
+            "weight_id": None if weight is None else weight.id,
+            "src_value_id": ctx.src_value_of_recv(rs)}, None
+
+
+def _check_relation(g: S.GatherBlock, ctx: _Ctx) -> Optional[str]:
+    send = ctx.nodes.get(g.acc.send_id)
+    if send is None:
+        return f"send %{g.acc.send_id} missing from the IR"
+    derived, reason = _walk_typed(send, ctx)
+    if derived is None:
+        return reason
+    for field in ("bmm_id", "weight_id", "src_value_id"):
+        if getattr(g, field) != derived[field]:
+            return (f"{field} %{getattr(g, field)} != %{derived[field]} "
+                    f"from the IR")
+    if g.covered != derived["covered"]:
+        return (f"covered {sorted(g.covered)} != "
+                f"{sorted(derived['covered'])}")
+    return None
+
+
 _KERNEL_CHECKS = {
     S.KERNEL_SPMM: ("ZS104", lambda g, p, ctx, plan: _check_spmm(g, ctx)),
     S.KERNEL_SPMM_WEIGHTED: ("ZS105",
                              lambda g, p, ctx, plan: _check_spmm_weighted(g, ctx)),
     S.KERNEL_SEGMENT_SOFTMAX: ("ZS106", _check_softmax),
+    S.KERNEL_RELATION: ("ZS112",
+                        lambda g, p, ctx, plan: _check_relation(g, ctx)),
 }
 
 
@@ -230,6 +288,12 @@ def explain_scan_fallback(g: S.GatherBlock, ctx: _Ctx) -> str:
     val = ctx.nodes.get(send.inputs[0])
     if val is None:
         return f"gather operand %{send.inputs[0]} missing from the IR"
+    if val.op == "bmm_edge" or (val.op == "mul" and any(
+            ctx.nodes[i].op == "bmm_edge" for i in val.inputs)):
+        _, reason = _walk_typed(send, ctx)
+        return (f"typed gather (bmm_edge) left on the scan path: {reason}"
+                if reason else "typed gather (bmm_edge) left on the scan "
+                "path: this schedule has no relation-grouped layout")
     if val.op == "recvSrc":
         cons = ctx.consumers.get(val.id, [])
         return (f"recvSrc message %{val.id} has {len(cons)} consumers "
@@ -358,7 +422,7 @@ def verify_schedule(sp: S.ScheduledProgram) -> List[Diagnostic]:
                          f"{plan.level.get(g.acc.send_id)} but is scheduled "
                          f"at phase {phase.level}", **anchor))
 
-    # --- kernel-tag legality (ZS104/105/106) + missed-kernel lint (ZS110) --
+    # --- kernel-tag legality (ZS104/105/106/112) + missed-kernel lint (ZS110)
     for phase, g in all_blocks:
         if g.kernel == S.KERNEL_SCAN:
             if sp.kernel_dispatch:

@@ -275,22 +275,23 @@ class CompiledGNN:
     verify: bool = True
     #: non-fatal findings accumulated by the verification hooks
     diagnostics: List = dataclasses.field(default_factory=list, repr=False)
-    _schedules: Dict[bool, object] = dataclasses.field(default_factory=dict,
-                                                       repr=False)
+    _schedules: Dict[Tuple[bool, bool], object] = dataclasses.field(
+        default_factory=dict, repr=False)
 
     @property
     def n_layers(self) -> int:
         """GNN layers in the lowered program (stacked models; 1 otherwise)."""
         return self.trace.n_layers
 
-    def schedule(self, kernel_dispatch: bool = True):
+    def schedule(self, kernel_dispatch: bool = True, typed: bool = True):
         """The :class:`~repro.core.schedule.ScheduledProgram` every engine
-        interprets (cached per dispatch mode)."""
+        interprets (cached per dispatch mode); ``typed=False`` keeps typed
+        gathers on the scan path (engines without the relation layout)."""
         from . import schedule as S
 
-        key = bool(kernel_dispatch)
+        key = (bool(kernel_dispatch), bool(typed))
         if key not in self._schedules:
-            sp = S.lower(self.plan, kernel_dispatch=key)
+            sp = S.lower(self.plan, kernel_dispatch=key[0], typed=key[1])
             if self.verify:
                 from . import analysis as A
 
@@ -299,7 +300,8 @@ class CompiledGNN:
                 if errs:
                     raise A.VerificationError(
                         diags, context=f"schedule({self.name}, "
-                                       f"kernel_dispatch={key})")
+                                       f"kernel_dispatch={key[0]}, "
+                                       f"typed={key[1]})")
                 self.diagnostics.extend(diags)
             self._schedules[key] = sp
         return self._schedules[key]

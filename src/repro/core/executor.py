@@ -47,10 +47,8 @@ def apply_compute(op: str, attrs: Dict, params: Dict[str, Array], args: Sequence
     if op == "bias_add":
         return args[0] + params[attrs["weight"]]
     if op == "bmm_edge":
-        x, et = args
-        w = params[attrs["weight"]]  # (n_types, d_in, d_out)
-        sel = w[et[..., 0].astype(jnp.int32)]
-        return jnp.einsum("ef,efo->eo", x, sel, precision=_HIGHEST)
+        return typed_transform(args[0], args[1][..., 0].astype(jnp.int32),
+                               params[attrs["weight"]])
     if op == "add":
         return args[0] + args[1]
     if op == "sub":
@@ -82,6 +80,66 @@ def apply_compute(op: str, attrs: Dict, params: Dict[str, Array], args: Sequence
     if op == "rsqrt":
         return jax.lax.rsqrt(args[0])
     raise NotImplementedError(op)
+
+
+#: per-chunk budget of gathered block weights (f32 elements, 64 MB):
+#: bounds the typed transform's memory whatever the edge count
+_TYPED_CHUNK_ELEMS = 1 << 24
+
+
+def typed_transform(x: Array, et: Array, w: Array) -> Array:
+    """``out_e = x_e @ blockdiag(w[et_e])`` for every row ``e``.
+
+    ``w`` is (n_types, n_blocks, k, m).  Rows run in chunks whose gathered
+    block weights (chunk, n_blocks, k, m) stay within ``_TYPED_CHUNK_ELEMS``,
+    so no (E, d_in, d_out) weight is ever materialized; each block product
+    is an f32 multiply-add over its k inputs (exact, no MXU pass)."""
+    w = jnp.asarray(w)
+    n_rows = x.shape[0]
+    _, nb, k, m = w.shape
+    chunk = max(1, min(n_rows, _TYPED_CHUNK_ELEMS // (nb * k * m)))
+    n_chunks = -(-n_rows // chunk)
+    pad = n_chunks * chunk - n_rows
+    xb = jnp.pad(x, ((0, pad), (0, 0))).reshape(n_chunks, chunk, nb, k)
+    eb = jnp.pad(et, (0, pad)).reshape(n_chunks, chunk)
+
+    def one(args):
+        xc, ec = args
+        return jnp.sum(xc[..., None] * w[ec], axis=-2).reshape(chunk, nb * m)
+
+    out = jax.lax.map(one, (xb, eb))
+    return out.reshape(n_chunks * chunk, nb * m)[:n_rows]
+
+
+def slot_edge_value(g, sp, params, full, estore, rel,
+                    n_vertices: int) -> Array:
+    """A relation gather block's per-edge weight on the rows of a relation
+    layout (``rel``: its ``slot_*`` arrays): the block's edge closure with
+    edge inputs read by ``slot_gid`` and endpoint values (``full(nid)``: a
+    vertex value on every row) by ``slot_src``/``slot_dst``; 1 where the
+    block has no weight, 0 on padded rows."""
+    valid = rel["slot_dst"] < n_vertices
+    if g.weight_id is None:
+        return valid.astype(jnp.float32)
+    gid = rel["slot_gid"]
+    env: Dict[int, Array] = {}
+
+    def look(nid: int) -> Array:
+        if nid in env:
+            return env[nid]
+        e = estore[nid]
+        # a width-1 input is gathered as a flat vector, not (E, 1) rows
+        return e[:, 0][gid][:, None] if e.shape[1] == 1 else e[gid]
+
+    rows = {"recvSrc": rel["slot_src"],
+            "recvDst": jnp.minimum(rel["slot_dst"], n_vertices - 1)}
+    for n in g.edge_nodes:
+        if n.op in rows:
+            env[n.id] = full(sp.scatter_value_of[n.id])[rows[n.op]]
+        else:
+            env[n.id] = apply_compute(n.op, n.attrs, params,
+                                      [look(i) for i in n.inputs])
+    return jnp.where(valid, look(g.weight_id)[:, 0], 0.0)
 
 
 # ---------------------------------------------------------------------------
@@ -224,6 +282,28 @@ class _TiledRun:
         return p, src_rows, esrc, edst_global, egid
 
     # -- kernel-tagged gather blocks -----------------------------------------
+    def _run_relation(self, g: S.GatherBlock, phase: S.Phase) -> None:
+        """A typed gather over the tile set's relation-grouped layout."""
+        from ..kernels.relation.ops import layout_operands, relation_aggregate
+        from .tiling import relation_layout
+
+        V = self.graph.n_vertices
+        bmm = next(seg.nodes[g.bmm_id] for seg in self.sp.prog.segments
+                   if g.bmm_id in seg.nodes)
+        rel = layout_operands(relation_layout(self.tiles,
+                                              bmm.attrs["wshape"][0]))
+
+        def full(nid: int) -> Array:
+            if nid in self.vstore:
+                return self.vstore[nid]
+            return self._eval_vertex(phase.src.nodes, jnp.arange(V))[nid]
+
+        scale = slot_edge_value(g, self.sp, self.params, full, self.estore,
+                                rel, V)
+        self.vstore[g.acc.recv_id] = relation_aggregate(
+            full(g.src_value_id), scale, self.params[bmm.attrs["weight"]],
+            rel, n_out=V)
+
     def _run_kernel_gathers(self, phase: S.Phase) -> None:
         from ..kernels.tile_spmm import ops as tops
         from ..kernels.tile_spmm.kernel import tile_flags
@@ -236,6 +316,9 @@ class _TiledRun:
         pmask = np.isin(np.arange(P), t.part_id)
 
         for g in phase.kernel_gathers():
+            if g.kernel == S.KERNEL_RELATION:
+                self._run_relation(g, phase)
+                continue
             # per-tile source values (padded rows; padding never contributes)
             xsrc_rows = []
             edge_vals = []

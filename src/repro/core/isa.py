@@ -65,7 +65,9 @@ def _compute_instr(node: IR.IRNode, rows: str) -> Instr:
         k, n = node.attrs["wshape"][-2], node.attrs["wshape"][-1]
         return Instr("GEMM", "MU", rows, k=k, n=n, weight_bytes=4 * k * n, tag=node.op)
     if node.op == "bmm_edge":
-        k, n = node.attrs["wshape"][-2], node.attrs["wshape"][-1]
+        # block-diagonal (n_types, n_blocks, k, m): k MACs per output lane
+        _, nb, k, m = node.attrs["wshape"]
+        n = nb * m
         # index-guided BMM: per-row weight select defeats weight-stationarity
         return Instr("BMM", "MU", rows, k=k, n=n, weight_bytes=4 * k * n, tag=node.op)
     if node.op == "gemv":
@@ -89,6 +91,11 @@ def _kernel_instrs(g, layout: str = "coo") -> List[Instr]:
     """
     from . import schedule as S
 
+    if g.kernel == S.KERNEL_RELATION:
+        # relation-grouped rows, either tile layout: per typed edge the
+        # scaled source-row gather, the block-diagonal transform and the
+        # destination sum on the VU (one TH lookup per edge)
+        return [Instr("GTHR.REL", "VU", "n_edge", n=g.acc.dim, tag=g.kernel)]
     if layout == "csr":
         if g.kernel == S.KERNEL_SPMM:
             # row-pointer walk + per-edge gather-accumulate of F-wide rows

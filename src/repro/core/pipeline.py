@@ -29,6 +29,13 @@ weight once on the dense ``(T, Dmax, Smax)`` tile grid, a destination
 column broadcast against a source row, and scales the tile's edge-count
 adjacency by it, instead of gathering endpoint values per padded edge slot.
 
+A gather block tagged ``pallas_relation`` (R-GCN's typed aggregation) runs
+over the tile set's relation layout (``tiling.relation_layout``, built at
+bind): each typed edge's source row is gathered and scaled by its edge
+weight, the relation kernel applies the block's relation weights, and the
+messages, gathered into destination order, are summed into their
+destinations by a second kernel.
+
 ``tiles`` may be a :class:`~repro.core.tiling.TileSet` (one global-pad
 bucket) or a :class:`~repro.core.tiling.BucketedTileSet`.
 """
@@ -43,9 +50,9 @@ import numpy as np
 from . import compiler as C
 from . import ir as IR
 from . import schedule as S
-from .executor import apply_compute, _NEG_INF
+from .executor import apply_compute, slot_edge_value, _NEG_INF
 from .tiling import (BucketedTileSet, ShardPlan, TileSet, exchange_sets,
-                     plan_shards)
+                     plan_shards, relation_layout)
 from ..gnn.graphs import Graph
 
 Array = Any
@@ -228,6 +235,21 @@ class PipelinedRunner:
         #: (T, Dmax, Smax) tile grid, ``edge`` per padded edge slot
         self.weight_paths = {"grid": len(self._grid_weights),
                              "edge": len(weighted) - len(self._grid_weights)}
+        nodes = {n.id: n for seg in self.sp.prog.segments
+                 for n in seg.nodes.values()}
+        bmms = {g.acc.recv_id: nodes[g.bmm_id] for ph in self.sp.phases
+                for g in ph.gathers if g.kernel == S.KERNEL_RELATION}
+        #: relation weights of each typed gather block, by its result
+        self._rel_weights = {r: n.attrs["weight"] for r, n in bmms.items()}
+        #: relation count of the typed gather blocks (``None``: untyped)
+        self.n_types = max((n.attrs["wshape"][0] for n in bmms.values()),
+                           default=None)
+        self._relations = (None if self.n_types is None
+                           else relation_layout(tiles, self.n_types))
+        #: rows of the relation-grouped layout last bound: real rows (typed
+        #: edges), padded rows and relation groups (``None``: untyped)
+        self.relation_rows = (None if self._relations is None
+                              else self._relations.counts())
         self._signature = (self.sp.structure_signature(),
                            tiles.shape_signature(), self.reorder_mode)
         self._operands: Optional[Tuple] = None   # lazy bind of ctor tiles
@@ -294,7 +316,14 @@ class PipelinedRunner:
             st = tiles.source if isinstance(tiles, BucketedTileSet) else tiles
             ta0 = _tile_arrays(st)
             kc0 = self._tile_const(st)
-        return (tas, kcs, ta0, kc0, _perm_operand(reordering))
+        rel = None
+        if self.n_types is not None:
+            from ..kernels.relation.ops import layout_operands
+            lay = (self._relations if tiles is self.tiles
+                   else relation_layout(tiles, self.n_types))
+            self.relation_rows = lay.counts()
+            rel = layout_operands(lay)
+        return (tas, kcs, ta0, kc0, _perm_operand(reordering), rel)
 
     # ------------------------------------------------------------------ run
     def _args(self, inputs, params, operands) -> Tuple:
@@ -322,7 +351,9 @@ class PipelinedRunner:
         return self(inputs, params, operands=self.bind(tiles, reordering))
 
     # ---------------------------------------------------------- trace-time
-    def _run(self, inputs, params, tas, kcs, ta0, kc0, perm) -> List[Array]:
+    def _run(self, inputs, params, tas, kcs, ta0, kc0, perm,
+             rel) -> List[Array]:
+        from ..kernels.relation.ops import relation_aggregate
         from ..kernels.tile_spmm.ops import (densify_edge_scores,
                                              densify_edge_weights)
 
@@ -478,6 +509,27 @@ class PipelinedRunner:
 
             # ---- kernel-dispatched gather blocks
             for g in phase.kernel_gathers():
+                if g.kernel == S.KERNEL_RELATION:
+                    with _stage("edge"):
+                        def full(nid):
+                            """A vertex value on every vertex row."""
+                            if nid in vstore:
+                                return vstore[nid]
+                            return eval_vertex(jnp.arange(V),
+                                               phase.src.nodes)[nid]
+
+                        h = full(g.src_value_id)
+                        scale = slot_edge_value(g, sp, params, full, estore,
+                                                rel, V)
+                    out = relation_aggregate(
+                        h, scale, params[self._rel_weights[g.acc.recv_id]],
+                        rel, n_out=V, stage=_stage)
+                    with _stage("vertex"):
+                        pstore[g.acc.recv_id] = jnp.where(
+                            pad_valid, out[safe_pad_ids], 0.0)
+                        if g.acc.recv_id in tile_side_reads:
+                            vstore[g.acc.recv_id] = out
+                    continue
                 if g.kernel == S.KERNEL_SEGMENT_SOFTMAX:
                     def tile_se(xs):
                         senv = eval_vertex(xs["src_ids"], phase.src.nodes)
@@ -905,7 +957,9 @@ class ShardedRunner:
             kernel_dispatch = tile_kernel is not None
         self.c = compiled
         self.kernel_dispatch = bool(kernel_dispatch)
-        self.sp: S.ScheduledProgram = compiled.schedule(self.kernel_dispatch)
+        # no relation-grouped layout here yet: typed gathers keep the scan
+        self.sp: S.ScheduledProgram = compiled.schedule(self.kernel_dispatch,
+                                                        typed=False)
         self.graph = graph
         self.tiles = tiles
         self.layout = getattr(tiles, "layout", "coo")

@@ -23,8 +23,11 @@ Blocks per phase:
   - ``pallas_segment_softmax`` for the GAT edge-softmax motif — the THREE
     gather levels (max, sum-of-exp, weighted sum) fuse into one online-softmax
     block (see :func:`_match_softmax_motifs`)
-  - ``scan``                   fallback (BMM / max / mean phases, or when
-    kernel dispatch is off)
+  - ``pallas_relation``        for  bmm_edge(recvSrc, etype) [* α] ->
+    sendDstSum  (R-GCN's typed aggregation over a relation-grouped layout;
+    etype an edge input, α a per-edge scalar)
+  - ``scan``                   fallback (max / mean phases, or when kernel
+    dispatch is off)
 
 * :class:`DstBlock`  — destination-replica vertex compute, evaluated per
   partition, publishing phase results into the global vertex store.
@@ -50,8 +53,10 @@ KERNEL_SCAN = "scan"
 KERNEL_SPMM = "pallas_spmm"
 KERNEL_SPMM_WEIGHTED = "pallas_spmm_weighted"
 KERNEL_SEGMENT_SOFTMAX = "pallas_segment_softmax"
+KERNEL_RELATION = "pallas_relation"
 
-PALLAS_KERNELS = (KERNEL_SPMM, KERNEL_SPMM_WEIGHTED, KERNEL_SEGMENT_SOFTMAX)
+PALLAS_KERNELS = (KERNEL_SPMM, KERNEL_SPMM_WEIGHTED, KERNEL_SEGMENT_SOFTMAX,
+                  KERNEL_RELATION)
 
 
 # ---------------------------------------------------------------------------
@@ -106,8 +111,11 @@ class GatherBlock:
     kernel: str = KERNEL_SCAN
     #: vertex node whose value feeds the kernel's dense X operand
     src_value_id: Optional[int] = None
-    #: edge node computing the per-edge scalar weight α (weighted SpMM)
+    #: edge node computing the per-edge scalar weight α (weighted SpMM;
+    #: optional for the relation block)
     weight_id: Optional[int] = None
+    #: the typed-transform node (``bmm_edge``) of a relation block
+    bmm_id: Optional[int] = None
     #: edge node computing the per-edge score e (segment softmax)
     score_id: Optional[int] = None
     #: edge nodes (topo order) to evaluate for the kernel's edge operands
@@ -344,11 +352,13 @@ def _match_softmax_motifs(plan: SDEPlan, nodes: Dict[int, IR.IRNode],
 
 def _classify_gather(send: IR.IRNode, nodes: Dict[int, IR.IRNode],
                      send_of_comm: Dict[int, int],
-                     consumers: Dict[int, List[IR.IRNode]]) -> Tuple[str, Dict]:
+                     consumers: Dict[int, List[IR.IRNode]],
+                     typed: bool = True) -> Tuple[str, Dict]:
     """Pattern-match one gather send onto a hardware block.
 
     The matched chain must be single-consumer so subsuming it into the
-    kernel block leaves nothing dangling for the scan path.
+    kernel block leaves nothing dangling for the scan path.  ``typed=False``
+    leaves typed (``bmm_edge``) gathers on the scan path.
     """
     def private(nid: int) -> bool:
         return len(consumers.get(nid, [])) == 1
@@ -371,7 +381,38 @@ def _classify_gather(send: IR.IRNode, nodes: Dict[int, IR.IRNode],
                 return KERNEL_SPMM_WEIGHTED, {
                     "src_value_id": src_value, "weight_id": w.id,
                     "covered": {val.id, rs.id}}
+    if typed:
+        return _classify_typed(send, nodes, send_of_comm, private)
     return KERNEL_SCAN, {}
+
+
+def _classify_typed(send: IR.IRNode, nodes: Dict[int, IR.IRNode],
+                    send_of_comm: Dict[int, int], private) -> Tuple[str, Dict]:
+    """``bmm_edge(recvSrc, etype) [* α] -> sendDstSum``: the typed
+    aggregation over a relation-grouped layout.  ``etype`` must be an edge
+    input (graph structure: the layout is built from the tiles' edge types),
+    α a per-edge scalar that is not a recv, every link private."""
+    val = nodes[send.inputs[0]]
+    covered = {val.id}
+    weight = None
+    if val.op == "mul" and private(val.id):
+        a, b = (nodes[i] for i in val.inputs)
+        bmm, weight = (a, b) if a.op == "bmm_edge" else (b, a)
+        if weight.dim != 1 or weight.is_recv():
+            return KERNEL_SCAN, {}
+        covered.add(bmm.id)
+    else:
+        bmm = val
+    if bmm.op != "bmm_edge" or not private(bmm.id):
+        return KERNEL_SCAN, {}
+    rs, et = (nodes[i] for i in bmm.inputs)
+    if rs.op != "recvSrc" or not private(rs.id) or et.op != "input":
+        return KERNEL_SCAN, {}
+    covered.add(rs.id)
+    return KERNEL_RELATION, {
+        "src_value_id": nodes[send_of_comm[rs.comm_id]].inputs[0],
+        "weight_id": None if weight is None else weight.id,
+        "bmm_id": bmm.id, "covered": covered}
 
 
 # ---------------------------------------------------------------------------
@@ -397,13 +438,16 @@ def _edge_closure(targets: Sequence[int], nodes: Dict[int, IR.IRNode],
 _GATHER_KIND = {"sendDstSum": "sum", "sendDstMax": "max", "sendDstMean": "mean"}
 
 
-def lower(plan: SDEPlan, kernel_dispatch: bool = True) -> ScheduledProgram:
+def lower(plan: SDEPlan, kernel_dispatch: bool = True,
+          typed: bool = True) -> ScheduledProgram:
     """Lower an SDE plan into the explicit scheduled phase program.
 
     ``kernel_dispatch=False`` tags every gather ``scan`` and disables motif
-    fusion — the pure multi-phase schedule of the paper.  The result is the
-    single source of truth for levels, roles, and block membership: engines
-    must not consult ``plan.level`` / ``plan.role`` themselves.
+    fusion — the pure multi-phase schedule of the paper.  ``typed=False``
+    keeps typed gathers (``bmm_edge``) on the scan path under kernel
+    dispatch, for engines without the relation-grouped layout.  The result
+    is the single source of truth for levels, roles, and block membership:
+    engines must not consult ``plan.level`` / ``plan.role`` themselves.
     """
     prog = plan.prog
     prog.rebuild_channels()
@@ -484,11 +528,12 @@ def lower(plan: SDEPlan, kernel_dispatch: bool = True) -> ScheduledProgram:
                             value_id=send.inputs[0],
                             recv_id=recv_of_comm[send.comm_id])
             kernel, extra = (_classify_gather(send, nodes, send_of_comm,
-                                              consumers)
+                                              consumers, typed)
                              if kernel_dispatch else (KERNEL_SCAN, {}))
             g = GatherBlock(acc=acc, kernel=kernel,
                             src_value_id=extra.get("src_value_id"),
-                            weight_id=extra.get("weight_id"))
+                            weight_id=extra.get("weight_id"),
+                            bmm_id=extra.get("bmm_id"))
             if kernel != KERNEL_SCAN:
                 g.covered = set(extra.get("covered", set())) | {send.id}
                 if g.weight_id is not None:

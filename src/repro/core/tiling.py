@@ -62,6 +62,10 @@ class TileSet:
     # instead of scanning padded edge slots.
     layout: str = "coo"
     row_ptr: Optional[np.ndarray] = None  # (T, D_max+1) int32, csr only
+    #: (n_edges,) int32 relation id per global edge (``edge_gid`` indexes
+    #: it); ``None`` for an untyped graph.  Structure, like the edge lists:
+    #: the relation-grouped layout (:func:`relation_layout`) is built from it
+    edge_type: Optional[np.ndarray] = None
 
     @property
     def n_tiles(self) -> int:
@@ -214,7 +218,7 @@ def grid_tile(graph: Graph, n_dst_parts: int, n_src_parts: int,
         part_start=db[:-1].astype(np.int32),
         part_size=np.diff(db).astype(np.int32),
         n_dst_parts=n_dst_parts, n_src_parts=n_src_parts, sparse=sparse,
-        n_vertices=V, n_edges=E)
+        n_vertices=V, n_edges=E, edge_type=graph.edge_type)
     return csr_tiles(ts) if layout == "csr" else ts
 
 
@@ -319,6 +323,10 @@ class BucketedTileSet:
     def part_size(self) -> np.ndarray:
         return self.source.part_size
 
+    @property
+    def edge_type(self) -> Optional[np.ndarray]:
+        return self.source.edge_type
+
     def tiles_of_partition(self, p: int) -> np.ndarray:
         return np.nonzero(self.part_id == p)[0]
 
@@ -368,7 +376,8 @@ def _repack(tiles: TileSet, idx: np.ndarray, pad_multiple: int) -> TileSet:
         n_dst_parts=tiles.n_dst_parts, n_src_parts=tiles.n_src_parts,
         sparse=tiles.sparse, n_vertices=tiles.n_vertices, n_edges=tiles.n_edges,
         layout=tiles.layout,
-        row_ptr=None if tiles.row_ptr is None else tiles.row_ptr[idx].copy())
+        row_ptr=None if tiles.row_ptr is None else tiles.row_ptr[idx].copy(),
+        edge_type=tiles.edge_type)
 
 
 def bucket_tiles(tiles: TileSet, n_buckets: int = 4,
@@ -474,7 +483,140 @@ def pad_tileset(tiles: TileSet, n_tiles: int, s_max: int, e_max: int) -> TileSet
         part_start=tiles.part_start, part_size=tiles.part_size,
         n_dst_parts=tiles.n_dst_parts, n_src_parts=tiles.n_src_parts,
         sparse=tiles.sparse, n_vertices=tiles.n_vertices, n_edges=tiles.n_edges,
-        layout=tiles.layout, row_ptr=row_ptr)
+        layout=tiles.layout, row_ptr=row_ptr, edge_type=tiles.edge_type)
+
+
+# ---------------------------------------------------------------------------
+# relation-grouped layout of typed edges (R-GCN's typed aggregation)
+# ---------------------------------------------------------------------------
+
+#: rows of one relation-grouped block (one relation's weights per block);
+#: also the destinations of one partition, and the message rows of one
+#: tile, of the destination sum
+RELATION_BLOCK_ROWS = 128
+
+
+@dataclasses.dataclass
+class RelationLayout:
+    """A tile set's typed edges laid out twice, structure only (built once
+    per tile set, never from features):
+
+    * **grouped**: sorted by (relation, destination, source) and cut into
+      blocks of :data:`RELATION_BLOCK_ROWS` rows that each carry one
+      relation; parallel edges of different relations between one pair
+      stay separate rows.  Padded rows carry ``dst = n_vertices`` and
+      ``src = gid = 0``;
+    * **destination tiles**: the grouped layout's real rows in destination
+      order, cut into tiles of :data:`RELATION_BLOCK_ROWS` rows within one
+      partition of as many destinations (partition-major; every partition
+      has a tile; filler tiles extend the last partition).  ``sum_src``
+      names each tile row's grouped row, ``sum_dst`` its destination
+      within the partition (-1 on padded rows).
+
+    Both are padded to capacities that follow from the tile shape alone."""
+
+    slot_src: np.ndarray    # (n_slots,) int32 global source id
+    slot_dst: np.ndarray    # (n_slots,) int32 global destination id
+    slot_gid: np.ndarray    # (n_slots,) int32 global edge id (edge inputs)
+    block_rel: np.ndarray   # (n_blocks,) int32 relation of each block
+    sum_src: np.ndarray     # (n_tiles * K,) int32 grouped row of a tile row
+    sum_dst: np.ndarray     # (n_tiles, 1, K) int32 partition-local dst
+    sum_part: np.ndarray    # (n_tiles,) int32 destination partition
+    n_real: int             # real rows (typed edges)
+    n_groups: int           # relations with at least one edge
+
+    @property
+    def n_slots(self) -> int:
+        return int(self.slot_src.shape[0])
+
+    @property
+    def n_padded(self) -> int:
+        return self.n_slots - self.n_real
+
+    def counts(self) -> dict:
+        """Row counts: real rows, the grouped layout's padded rows, relation
+        groups, and the destination tiles' padded rows."""
+        return dict(real_rows=self.n_real, padded_rows=self.n_padded,
+                    relation_groups=self.n_groups,
+                    sum_padded_rows=int(self.sum_src.shape[0]) - self.n_real)
+
+
+def relation_capacity(tiles, n_types: int, block_rows: int = RELATION_BLOCK_ROWS
+                      ) -> Tuple[int, int]:
+    """(grouped blocks, destination tiles) of a relation layout for any
+    tile set of ``tiles``' shape.  Over at most ``E`` padded edge slots,
+    ``sum_r ceil(n_r / K) <= floor(E / K) + min(R, E)`` and, over ``P``
+    destination partitions with at least one tile each, ``sum_p max(1,
+    ceil(n_p / K)) <= floor(E / K) + P``: the layout's shape follows from
+    the tile shape signature, so a runner never recompiles for a new edge
+    list."""
+    e = int(tiles.padded_edge_slots())
+    n_parts = -(-tiles.n_vertices // block_rows)
+    return max(1, e // block_rows + min(n_types, e)), e // block_rows + n_parts
+
+
+def relation_layout(tiles, n_types: int,
+                    block_rows: int = RELATION_BLOCK_ROWS) -> RelationLayout:
+    """Group a (bucketed) tile set's real edges by relation, and order the
+    grouped rows by destination; pad both to :func:`relation_capacity`."""
+    if tiles.edge_type is None:
+        raise ValueError("the relation-grouped layout needs typed tiles: "
+                         "tile a graph that has edge_type")
+    K, V = block_rows, tiles.n_vertices
+    buckets = (list(tiles.buckets) if isinstance(tiles, BucketedTileSet)
+               else [tiles])
+    src, dst, gid = [], [], []
+    for b in buckets:
+        real = np.arange(b.e_max)[None, :] < b.n_edge[:, None]
+        src.append(np.take_along_axis(b.src_ids, b.edge_src, axis=1)[real])
+        dst.append((b.part_start[b.part_id][:, None] + b.edge_dst)[real])
+        gid.append(b.edge_gid[real])
+    src, dst, gid = (np.concatenate(a).astype(np.int64)
+                     for a in (src, dst, gid))
+    rel = np.asarray(tiles.edge_type, np.int64)[gid]
+    if rel.size and (rel.min() < 0 or rel.max() >= n_types):
+        raise ValueError(f"edge types span [{rel.min()}, {rel.max()}], the "
+                         f"model has {n_types} relations")
+    order = np.lexsort((src, dst, rel))
+    src, dst, gid, rel = src[order], dst[order], gid[order], rel[order]
+    cap_blocks, cap_tiles = relation_capacity(tiles, n_types, K)
+
+    # grouped: real row i of relation r at its relation's first block + rank
+    counts = np.bincount(rel, minlength=n_types)
+    n_blocks = -(-counts // K)
+    first = np.concatenate([[0], np.cumsum(n_blocks)[:-1]]) * K
+    rank = np.arange(rel.size) - np.concatenate(
+        [[0], np.cumsum(counts)[:-1]])[rel]
+    at = first[rel] + rank
+    slot_src = np.zeros(cap_blocks * K, np.int32)
+    slot_dst = np.full(cap_blocks * K, V, np.int32)
+    slot_gid = np.zeros(cap_blocks * K, np.int32)
+    slot_src[at], slot_dst[at], slot_gid[at] = src, dst, gid
+    block_rel = np.zeros(cap_blocks, np.int32)
+    block_rel[:n_blocks.sum()] = np.repeat(np.arange(n_types), n_blocks)
+
+    # destination tiles: grouped rows in destination order
+    by_dst = np.argsort(dst, kind="stable")
+    n_parts = -(-V // K)
+    part = dst[by_dst] // K
+    per_part = np.bincount(part, minlength=n_parts)
+    tiles_of = np.maximum(1, -(-per_part // K))
+    first_tile = np.concatenate([[0], np.cumsum(tiles_of)[:-1]])
+    rank = np.arange(part.size) - np.concatenate(
+        [[0], np.cumsum(per_part)[:-1]])[part]
+    row = (first_tile[part] + rank // K) * K + rank % K
+    sum_src = np.zeros(cap_tiles * K, np.int32)
+    sum_dst = np.full(cap_tiles * K, -1, np.int32)
+    sum_src[row] = at[by_dst]
+    sum_dst[row] = dst[by_dst] - part * K
+    sum_part = np.full(cap_tiles, n_parts - 1, np.int32)
+    sum_part[:tiles_of.sum()] = np.repeat(np.arange(n_parts), tiles_of)
+    return RelationLayout(slot_src=slot_src, slot_dst=slot_dst,
+                          slot_gid=slot_gid, block_rel=block_rel,
+                          sum_src=sum_src,
+                          sum_dst=sum_dst.reshape(cap_tiles, 1, K),
+                          sum_part=sum_part, n_real=int(rel.size),
+                          n_groups=int(np.count_nonzero(counts)))
 
 
 @dataclasses.dataclass

@@ -125,12 +125,15 @@ class TT:
     def bmm_edge(self, w: "TT", etype: "TT") -> "TT":
         """Edge-type-guided batched matmul (R-GCN): out_e = x_e @ W[etype_e].
 
-        W: (n_types, dim_in, dim_out); etype: per-edge integer type ('E', dim=1).
+        W: (n_types, n_blocks, dim_in / n_blocks, dim_out / n_blocks), each
+        type's matrix block-diagonal with ``n_blocks`` blocks (``n_blocks=1``
+        is a full matrix); etype: per-edge integer type ('E', dim=1).
         """
         shape = w.node.attrs["shape"]
-        assert self.space == "E" and etype.space == "E"
-        assert shape[1] == self.dim
-        return self.trace.emit("bmm_edge", "E", [self.nid, w.nid, etype.nid], shape[-1])
+        assert self.space == "E" and etype.space == "E" and len(shape) == 4
+        assert shape[1] * shape[2] == self.dim
+        return self.trace.emit("bmm_edge", "E", [self.nid, w.nid, etype.nid],
+                               shape[1] * shape[3])
 
     # -- element-wise ops --------------------------------------------------------
     def _elw2(self, op: str, other: "TT") -> "TT":

@@ -2,7 +2,8 @@
 whole-graph programming model.
 
 GCN, GAT (1 head, as in the paper), GraphSAGE (maxpool aggregator), GGNN
-(GRU update), R-GCN (3 edge types, as in the paper).  For GAT and SAGE we
+(GRU update), R-GCN (3 edge types by default, as in the paper; any relation
+count and block-diagonal weights on request).  For GAT and SAGE we
 also provide the *naive* variants the paper uses to evaluate the compiler's
 E2V optimization (Fig 12): per-edge ops that a library author would normally
 hand-hoist are left on the edges, and the compiler must hoist them.
@@ -13,8 +14,9 @@ out_dim, prefix=...) -> TT`` plus a thin single-layer ``build_X`` wrapper.
 paper evaluates (§8.1 runs multi-layer GCN/GAT/SAGE/GGNN/R-GCN): layer
 ``l``'s output tensor becomes layer ``l+1``'s input, parameters are
 per-layer (``l{l}.`` prefix), and structure-only inputs (``dnorm``,
-``etype``) are declared once and shared — the compiler's cross-layer
-redundancy pass deduplicates the per-layer re-scatters they induce.
+``etype``, ``rnorm``) are declared once and shared — the compiler's
+cross-layer redundancy pass deduplicates the per-layer re-scatters they
+induce.
 """
 from __future__ import annotations
 
@@ -118,15 +120,32 @@ def layer_ggnn(tr: GnnTrace, g: GraphRef, x: TT, out_dim: Optional[int] = None, 
 
 
 def layer_rgcn(tr: GnnTrace, g: GraphRef, x: TT, out_dim: int, *,
-               etype: TT, prefix: str = "", n_types: int = 3) -> TT:
-    """R-GCN with 3 randomly-assigned edge types (paper §8.1): per-edge
-    type-selected weights — an index-guided BMM that canNOT be hoisted."""
-    wr = tr.param(prefix + "W_rel", (n_types, x.dim, out_dim))
+               etype: TT, rnorm: TT, prefix: str = "", n_types: int = 3,
+               n_blocks: int = 1, relu: bool = False) -> TT:
+    """R-GCN layer (Schlichtkrull et al., arXiv:1703.06103, eq. 2):
+
+        h_i' = σ( Σ_r Σ_{j ∈ N_i^r} (1/c_{i,r}) W_r h_j + W_0 h_i ),
+        c_{i,r} = |N_i^r|
+
+    ``W_r`` is block-diagonal with ``n_blocks`` blocks (the paper's block
+    decomposition, §2.2); ``n_blocks=1`` is a full (Fi, Fo) matrix per
+    relation.  ``etype`` is the per-edge relation id (an index-guided BMM
+    that cannot be hoisted onto the vertices, ZIPPER §8.1) and ``rnorm`` the
+    per-edge ``1/c_{i,r}``, both structure-only edge inputs.  σ is ReLU
+    where ``relu`` is set (every layer but the last, as DGL's link-prediction
+    encoder has it); the last layer's output is linear.  Departures from the
+    paper: the input is a dense entity embedding (the paper's one-hot input
+    times a first matrix) and no layer has a bias.
+    """
+    if x.dim % n_blocks or out_dim % n_blocks:
+        raise ValueError(f"R-GCN widths {x.dim}->{out_dim} do not split "
+                         f"into {n_blocks} blocks")
+    wr = tr.param(prefix + "W_rel", (n_types, n_blocks, x.dim // n_blocks,
+                                     out_dim // n_blocks))
     w0 = tr.param(prefix + "W_self", (x.dim, out_dim))
-    xs = g.scatter_src(x)
-    m = xs.bmm_edge(wr, etype)
-    h = g.gather_sum(m)
-    return (h + x.matmul(w0)).relu()
+    m = g.scatter_src(x).bmm_edge(wr, etype)
+    h = g.gather_sum(m * rnorm) + x.matmul(w0)
+    return h.relu() if relu else h
 
 
 def layer_gin(tr: GnnTrace, g: GraphRef, x: TT, out_dim: int, *,
@@ -179,10 +198,12 @@ def build_ggnn(tr: GnnTrace, g: GraphRef, in_dim: int = EMBED, out_dim: Optional
 
 
 def build_rgcn(tr: GnnTrace, g: GraphRef, in_dim: int = EMBED, out_dim: int = EMBED,
-               n_types: int = 3):
+               n_types: int = 3, n_blocks: int = 1):
     x = tr.input_vertex(in_dim, "x")
     et = tr.input_edge(1, "etype")
-    tr.mark_output(layer_rgcn(tr, g, x, out_dim, etype=et, n_types=n_types))
+    rn = tr.input_edge(1, "rnorm")
+    tr.mark_output(layer_rgcn(tr, g, x, out_dim, etype=et, rnorm=rn,
+                              n_types=n_types, n_blocks=n_blocks))
 
 
 def build_gin(tr: GnnTrace, g: GraphRef, in_dim: int = EMBED, out_dim: int = EMBED):
@@ -197,7 +218,9 @@ class ModelSpec:
     layer: Optional[Callable] = None     # stackable layer fn (None: 1-layer only)
     needs_etype: bool = False
     needs_dnorm: bool = False
-    n_edge_types: int = 3
+    #: the stacked variant applies ReLU after every layer but the last
+    #: (passed to ``layer`` as ``relu``)
+    last_linear: bool = False
     #: extra kwargs the stacked variant passes to ``layer`` (e.g. GCN's
     #: per-edge normalization, whose structure-only scatters repeat per layer)
     stacked_kw: Dict = dataclasses.field(default_factory=dict)
@@ -211,16 +234,30 @@ MODELS: Dict[str, ModelSpec] = {
     "sage": ModelSpec("sage", build_sage, layer_sage),
     "sage_naive": ModelSpec("sage_naive", build_sage_naive, None),
     "ggnn": ModelSpec("ggnn", build_ggnn, layer_ggnn),
-    "rgcn": ModelSpec("rgcn", build_rgcn, layer_rgcn, needs_etype=True),
+    "rgcn": ModelSpec("rgcn", build_rgcn, layer_rgcn, needs_etype=True,
+                      last_linear=True),
     "gin": ModelSpec("gin", build_gin, layer_gin),
 }
 
 PAPER_MODELS = ("gcn", "gat", "sage", "ggnn", "rgcn")
 
 
-def trace_named(name: str, in_dim: int = EMBED, out_dim: int = EMBED) -> GnnTrace:
+def trace_named(name: str, in_dim: int = EMBED, out_dim: int = EMBED,
+                **layer_kw) -> GnnTrace:
+    """Trace the single-layer ``name`` model; ``layer_kw`` goes to its
+    builder (rgcn: ``n_types``, ``n_blocks``)."""
     spec = MODELS[name]
-    return trace_model(lambda tr, g: spec.build(tr, g, in_dim, out_dim), name=name)
+    return trace_model(lambda tr, g: spec.build(tr, g, in_dim, out_dim,
+                                                **layer_kw), name=name)
+
+
+def n_edge_types(tr: GnnTrace) -> Optional[int]:
+    """Relation count a traced model was built for (the leading dimension
+    of its ``W_rel`` weights), or ``None`` for an untyped model."""
+    for name, shape in tr.params.items():
+        if name.rsplit(".", 1)[-1] == "W_rel":
+            return int(shape[0])
+    return None
 
 
 # ---------------------------------------------------------------------------
@@ -228,13 +265,16 @@ def trace_named(name: str, in_dim: int = EMBED, out_dim: int = EMBED) -> GnnTrac
 # ---------------------------------------------------------------------------
 
 def build_stacked(name: str, n_layers: int, in_dim: int = EMBED,
-                  hidden_dim: int = EMBED, out_dim: int = EMBED) -> List[Callable]:
+                  hidden_dim: int = EMBED, out_dim: int = EMBED,
+                  **layer_kw) -> List[Callable]:
     """Per-layer builders for a stacked ``name`` model, consumable by
     :func:`~repro.core.trace.trace_model`.
 
     Layer ``l`` receives layer ``l-1``'s output tensor; parameters get an
     ``l{l}.`` prefix (per-layer weights); structure-only inputs (``dnorm``,
-    ``etype``) are declared by the first layer and shared by all of them.
+    ``etype``, ``rnorm``) are declared by the first layer and shared by all
+    of them.  ``layer_kw`` goes to every layer (rgcn: ``n_types``,
+    ``n_blocks``).
     """
     spec = MODELS[name]
     if spec.layer is None:
@@ -254,19 +294,25 @@ def build_stacked(name: str, n_layers: int, in_dim: int = EMBED,
                 sh["dnorm"] = tr.input_vertex(1, "dnorm")
             if spec.needs_etype and "etype" not in sh:
                 sh["etype"] = tr.input_edge(1, "etype")
-            d_out = out_dim if layer_idx == n_layers - 1 else hidden_dim
+                sh["rnorm"] = tr.input_edge(1, "rnorm")
+            last = layer_idx == n_layers - 1
+            d_out = out_dim if last else hidden_dim
+            kw = (dict(layer_kw, relu=not last) if spec.last_linear
+                  else layer_kw)
             return spec.layer(tr, g, x, d_out, prefix=f"l{layer_idx}.",
-                              **sh, **spec.stacked_kw)
+                              **sh, **spec.stacked_kw, **kw)
         return build
 
     return [make(layer) for layer in range(n_layers)]
 
 
 def trace_stacked(name: str, n_layers: int, in_dim: int = EMBED,
-                  hidden_dim: int = EMBED, out_dim: int = EMBED) -> GnnTrace:
+                  hidden_dim: int = EMBED, out_dim: int = EMBED,
+                  **layer_kw) -> GnnTrace:
     """Trace an ``n_layers``-deep stack of ``name`` layers (one program)."""
     return trace_model(
-        build_stacked(name, n_layers, in_dim, hidden_dim, out_dim),
+        build_stacked(name, n_layers, in_dim, hidden_dim, out_dim,
+                      **layer_kw),
         name=f"{name}_x{n_layers}")
 
 
@@ -278,9 +324,19 @@ def init_params(tr: GnnTrace, seed: int = 0) -> Dict[str, np.ndarray]:
     rng = np.random.default_rng(seed)
     params = {}
     for name, shape in tr.params.items():
-        fan_in = shape[0] if len(shape) > 1 else 1
+        # a matrix's rows; for a stack of (block) matrices, a block's rows
+        fan_in = shape[-2] if len(shape) > 1 else 1
         params[name] = (rng.standard_normal(shape) / np.sqrt(max(fan_in, 1))).astype(np.float32)
     return params
+
+
+def relation_norm(dst: np.ndarray, edge_type: np.ndarray,
+                  n_vertices: int) -> np.ndarray:
+    """Per edge ``1 / c_{i,r}``: one over the number of edges of the edge's
+    relation into its destination (R-GCN's normalisation)."""
+    key = edge_type.astype(np.int64) * n_vertices + dst
+    _, inv, cnt = np.unique(key, return_inverse=True, return_counts=True)
+    return (1.0 / cnt[inv.reshape(-1)]).astype(np.float32)
 
 
 def init_inputs(tr: GnnTrace, graph: Graph, seed: int = 0) -> Dict[str, np.ndarray]:
@@ -296,6 +352,10 @@ def init_inputs(tr: GnnTrace, graph: Graph, seed: int = 0) -> Dict[str, np.ndarr
         elif name == "etype":
             assert graph.edge_type is not None, "graph has no edge types"
             inputs[name] = graph.edge_type[:, None].astype(np.float32)
+        elif name == "rnorm":
+            assert graph.edge_type is not None, "graph has no edge types"
+            inputs[name] = relation_norm(graph.dst, graph.edge_type,
+                                         graph.n_vertices)[:, None]
         elif n.space == "V":
             inputs[name] = rng.standard_normal((graph.n_vertices, n.dim)).astype(np.float32)
         else:

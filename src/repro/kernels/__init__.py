@@ -4,5 +4,8 @@
   moe_dispatch/     capacity-bucket grouped FFN (ZIPPER tiling over tokens)
   tile_spmm/        block-dense SpMM over graph tiles (the paper's dataflow)
   segment_softmax/  GAT edge softmax, single-pass online variant
+  relation/         R-GCN typed aggregation: relation-grouped transform and
+                    destination sum (kernel.py, ops.py; the oracle is
+                    ``core.executor.typed_transform``)
 Each provides kernel.py (Pallas), ops.py (jit wrapper), ref.py (oracle).
 """

@@ -301,10 +301,14 @@ def test_missed_kernel_lint_explains_scan_fallbacks():
     lints = [d for d in A.verify_schedule(sp) if d.code == "ZS110"]
     assert lints and all(d.severity == A.INFO for d in lints)
     assert any("max-reduce" in d.message for d in lints)
-    # rgcn: per-edge-type bmm feeds the gather — no kernel matches
+    # rgcn: the typed bmm gather runs on the relation kernel — no lint; a
+    # schedule without the relation layout (typed=False) says why it scans
     sp = _compiled("rgcn").schedule(True)
+    assert sp.gather_kernel(0) == S.KERNEL_RELATION
+    assert not [d for d in A.verify_schedule(sp) if d.code == "ZS110"]
+    sp = _compiled("rgcn").schedule(True, typed=False)
     lints = [d for d in A.verify_schedule(sp) if d.code == "ZS110"]
-    assert any("bmm_edge" in d.message for d in lints)
+    assert lints and all("bmm_edge" in d.message for d in lints)
     # without kernel dispatch the scan path is intended: no lint
     sp = _compiled("sage").schedule(False)
     assert not [d for d in A.verify_schedule(sp) if d.code == "ZS110"]

@@ -29,7 +29,9 @@ def _stacked(name, n_layers, dim=DIM):
 
 def _layer_by_layer_oracle(name, n_layers, g, inputs, params, dim=DIM):
     """Chain n_layers SINGLE-layer whole-graph references: layer l's output
-    becomes layer l+1's input, per-layer params stripped of their prefix."""
+    becomes layer l+1's input, per-layer params stripped of their prefix.
+    A model whose stack is linear only in its last layer (rgcn) gets the
+    ReLU the stack applies between layers."""
     x = np.asarray(inputs["x"])
     for layer in range(n_layers):
         tr_l = models.trace_named(name, dim, dim)
@@ -37,10 +39,12 @@ def _layer_by_layer_oracle(name, n_layers, g, inputs, params, dim=DIM):
         p_l = {k[len(prefix):]: v for k, v in params.items()
                if k.startswith(prefix)}
         inp_l = {"x": x}
-        for shared in ("dnorm", "etype"):
+        for shared in ("dnorm", "etype", "rnorm"):
             if shared in inputs:
                 inp_l[shared] = inputs[shared]
         x = np.asarray(executor.run_reference(tr_l, g, inp_l, p_l)[0])
+        if models.MODELS[name].last_linear and layer < n_layers - 1:
+            x = np.maximum(x, 0.0)
     return x
 
 

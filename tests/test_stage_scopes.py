@@ -16,12 +16,13 @@ from repro.core import compiler, pipeline, tiling
 from repro.gnn import graphs, models
 
 STAGES = {"zipper.vertex", "zipper.edge", "zipper.densify", "zipper.kernel"}
-CASES = [("gcn", "coo", True), ("gat", "coo", True), ("gcn", "csr", True),
-         ("gat", "csr", True), ("gcn", "coo", False)]
+CASES = [("gcn", "coo", True), ("gat", "coo", True), ("rgcn", "coo", True),
+         ("gcn", "csr", True), ("gat", "csr", True), ("gcn", "coo", False)]
 
 
 def _runner(model, layout, kernel_dispatch):
-    g = graphs.random_graph(96, 400, seed=3, model="powerlaw")
+    g = graphs.random_graph(96, 400, seed=3, model="powerlaw",
+                            n_edge_types=3 if model == "rgcn" else None)
     tiles, _ = tiling.build_tiles(g, 3, 3, layout=layout)
     trace = models.trace_stacked(model, 2, 16, 16, 16)
     r = pipeline.PipelinedRunner(compiler.compile_gnn(trace), g, tiles,
@@ -61,7 +62,9 @@ def _scopes(hlo: str):
 @pytest.mark.parametrize("model,layout,kernel_dispatch", CASES)
 def test_stage_scopes_tag_the_program(model, layout, kernel_dispatch):
     got = _scopes(_compiled_text(model, layout, kernel_dispatch))
-    if kernel_dispatch and layout == "coo":
+    if model == "rgcn":                 # the relation path has no densify
+        assert got == STAGES - {"zipper.densify"}
+    elif kernel_dispatch and layout == "coo":
         assert got == STAGES
     elif kernel_dispatch:               # CSR kernels walk rows: no densify
         assert got == STAGES - {"zipper.densify"}
@@ -69,7 +72,7 @@ def test_stage_scopes_tag_the_program(model, layout, kernel_dispatch):
         assert got == {"zipper.vertex", "zipper.edge"}
 
 
-@pytest.mark.parametrize("model,layout,kernel_dispatch", CASES[:2])
+@pytest.mark.parametrize("model,layout,kernel_dispatch", CASES[:3])
 def test_stage_scopes_change_no_op(model, layout, kernel_dispatch,
                                    monkeypatch):
     scoped = _compiled_text(model, layout, kernel_dispatch)

@@ -1,8 +1,10 @@
-"""Compile the four tile kernels for a described TPU v5e chip, no chip needed.
+"""Compile the four tile kernels and the relation kernel for a described TPU
+v5e chip, no chip needed.
 
-Each case compiles one of the COO/CSR SpMM and segment-softmax kernels at
-F=128 for one chip of a ``v5e:2x2`` topology and asserts that the program
-holds a Mosaic kernel (``tpu_custom_call``).  Two kinds of shapes:
+Each tile case compiles one of the COO/CSR SpMM and segment-softmax kernels
+at F=128 for one chip of a ``v5e:2x2`` topology and asserts that the program
+holds a Mosaic kernel (``tpu_custom_call``); the relation kernel compiles at
+the R-GCN cell's shape and as one dense matrix.  Two kinds of tile shapes:
 
 * ``serving`` — a 16-graph request of ~64 V / 256 E graphs, canonicalized
   by :class:`~repro.serve.signature.ShapeRegistry` (``target_part=256``);
@@ -115,3 +117,55 @@ def test_kernel_compiles_for_v5e(kernel, shapes, one_chip, mosaic):
     out = compiled.out_info
     assert out.shape == (ts.n_dst_parts, int(ts.part_size.max()), F)
     assert np.dtype(out.dtype) == np.float32
+
+
+@pytest.mark.parametrize("form", ["lanes", "dense"])
+def test_relation_kernel_compiles_for_v5e(form, one_chip, mosaic):
+    """R-GCN's relation kernel: ``lanes`` at the FB15k-237 cell's shape (474
+    relations of 100 5x5 blocks, 640 lanes, 4,725 blocks of 128 rows),
+    ``dense`` for one full 128x128 matrix per relation (3 relations)."""
+    from repro.kernels.relation import kernel as RK
+    from repro.kernels.relation import ops as RO
+
+    rows = 128
+    if form == "lanes":
+        w_shape, n_blocks = (474, 100, 5, 5), 4725
+    else:
+        w_shape, n_blocks = (3, 1, 128, 128), 64
+    _, nb, k, m = w_shape
+    assert RO.form_of(w_shape) == form
+    w = jax.eval_shape(RO.kernel_weights,
+                       jax.ShapeDtypeStruct(w_shape, jnp.float32))
+    x = jax.eval_shape(functools.partial(RO.to_rows, w_shape=w_shape),
+                       jax.ShapeDtypeStruct((n_blocks * rows, nb * k),
+                                            jnp.float32))
+
+    def a(s):
+        return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+
+    fn = functools.partial(RK.relation_transform_pallas, block_rows=rows,
+                           form=form, k=k, m=m)
+    compiled = jax.jit(fn).lower(
+        a(x), a(w), a(jax.ShapeDtypeStruct((n_blocks,), jnp.int32))).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and f"%{RK.NAME}" in text
+    # square blocks: the output rows are as wide as the input rows
+    assert compiled.out_info.shape == x.shape
+
+
+def test_relation_sum_kernel_compiles_for_v5e(one_chip, mosaic):
+    """The destination-sum kernel at the R-GCN cell's shape: 4,839 tiles of
+    128 message rows of 640 lanes into 114 partitions of 128 rows."""
+    from repro.kernels.relation import kernel as RK
+
+    T, S, W, P = 4839, 128, 640, 114
+
+    def a(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    fn = functools.partial(RK.relation_sum_pallas, n_parts=P, part_rows=S)
+    compiled = jax.jit(fn).lower(a((T, 1, S), jnp.int32), a((T, S, W)),
+                                 a((T,), jnp.int32),
+                                 a((T,), jnp.int32)).compile()
+    assert f"%{RK.SUM_NAME}" in compiled.as_text()
+    assert compiled.out_info.shape == (P, S, W)
