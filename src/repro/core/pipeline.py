@@ -23,11 +23,17 @@ derives no levels or roles of its own.  Per phase:
 * ``scan``-tagged gathers run the pipelined ``lax.scan`` tile loop, one scan
   per bucket with shared accumulators.
 
-A weighted-SpMM block over COO tiles whose edge weight reads only its
-edge's endpoint vertex values (GCN's ``dn[src]·dn[dst]``) evaluates that
-weight once on the dense ``(T, Dmax, Smax)`` tile grid, a destination
-column broadcast against a source row, and scales the tile's edge-count
-adjacency by it, instead of gathering endpoint values per padded edge slot.
+A weighted-SpMM or segment-softmax block over COO tiles whose edge weight
+or score reads only its edge's endpoint vertex values (GCN's
+``dn[src]·dn[dst]``, GAT's ``leaky_relu(es[src] + ed[dst])``) evaluates
+it once on the dense ``(T, Dmax, Smax)`` tile grid, a destination column
+broadcast against a source row, instead of gathering endpoint values per
+padded edge slot.  The tile's edge count, one scatter per tile batch in
+the compiled forward, then scales a grid weight (``cnt·w``) or shifts a grid score
+(``s + log cnt``: ``cnt`` parallel edges of equal score), and the softmax
+kernel reads the tile's source rows as its values.  The per-slot path, with
+its per-edge-column score densify, remains for CSR tiles and for weights
+or scores that read an edge input or a parameter.
 
 A gather block tagged ``pallas_relation`` (R-GCN's typed aggregation) runs
 over the tile set's relation layout (``tiling.relation_layout``, built at
@@ -104,19 +110,26 @@ def _stage(name: str):
 _GRID_OPS = frozenset(IR.ELW_UNARY + IR.ELW_BINARY) - {"bias_add"}
 
 
-def _vertex_only_weight(g: S.GatherBlock) -> bool:
-    """Whether a weighted gather block's edge weight depends only on the
-    values at each edge's source and destination: its edge nodes are
-    ``recvSrc``/``recvDst`` and elementwise ops over earlier edge nodes,
-    so neither the weight nor any node it reads is an edge input (those
-    come in per ``edge_gid``)."""
+def _edge_result(g: S.GatherBlock) -> Optional[int]:
+    """The edge node a kernel gather block evaluates per edge: the score of
+    a segment-softmax block, the weight of a weighted-SpMM block."""
+    return (g.score_id if g.kernel == S.KERNEL_SEGMENT_SOFTMAX
+            else g.weight_id)
+
+
+def _vertex_only(g: S.GatherBlock) -> bool:
+    """Whether a gather block's edge weight or score (``_edge_result``)
+    depends only on the values at each edge's source and destination: its
+    edge nodes are ``recvSrc``/``recvDst`` and elementwise ops over earlier
+    edge nodes, so neither the result nor any node it reads is an edge
+    input (those come in per ``edge_gid``)."""
     seen = set()
     for n in g.edge_nodes:
         if n.op not in ("recvSrc", "recvDst") and (
                 n.op not in _GRID_OPS or not set(n.inputs) <= seen):
             return False
         seen.add(n.id)
-    return g.weight_id in seen
+    return _edge_result(g) in seen
 
 
 def _check_reorder_mode(expected: str, reordering) -> None:
@@ -225,16 +238,22 @@ class PipelinedRunner:
                              else reordering.mode)
         self.part_ids_pad, self.dmax = _padded_partition_ids(tiles)
         self._kernels = {g.kernel for ph in self.sp.phases for g in ph.gathers}
-        weighted = [g for ph in self.sp.phases for g in ph.kernel_gathers()
-                    if g.kernel == S.KERNEL_SPMM_WEIGHTED]
-        # recv ids of the weighted blocks whose weight runs on the tile grid
-        self._grid_weights = frozenset(
-            g.acc.recv_id for g in weighted
-            if self.layout != "csr" and _vertex_only_weight(g))
-        #: weighted gather blocks per weight path: ``grid`` on the dense
-        #: (T, Dmax, Smax) tile grid, ``edge`` per padded edge slot
-        self.weight_paths = {"grid": len(self._grid_weights),
-                             "edge": len(weighted) - len(self._grid_weights)}
+        blocks = [g for ph in self.sp.phases for g in ph.kernel_gathers()]
+        weighted = [g for g in blocks if g.kernel == S.KERNEL_SPMM_WEIGHTED]
+        softmax = [g for g in blocks if g.kernel == S.KERNEL_SEGMENT_SOFTMAX]
+        # recv ids of the blocks whose weight or score runs on the tile grid
+        self._on_grid = frozenset(
+            g.acc.recv_id for g in weighted + softmax
+            if self.layout != "csr" and _vertex_only(g))
+
+        def paths(gs):
+            n = sum(g.acc.recv_id in self._on_grid for g in gs)
+            return {"grid": n, "edge": len(gs) - n}
+
+        #: weighted-SpMM / segment-softmax gather blocks per path: ``grid``
+        #: on the dense (T, Dmax, Smax) tile grid, ``edge`` per padded slot
+        self.weight_paths = paths(weighted)
+        self.score_paths = paths(softmax)
         nodes = {n.id: n for seg in self.sp.prog.segments
                  for n in seg.nodes.values()}
         bmms = {g.acc.recv_id: nodes[g.bmm_id] for ph in self.sp.phases
@@ -310,12 +329,13 @@ class PipelinedRunner:
         else:
             kcs = tuple({} for _ in buckets)
         # the online-softmax state cannot be merged across buckets, so the
-        # segment-softmax block always runs over the unbucketed tile batch
+        # segment-softmax block always runs over the unbucketed tile batch:
+        # bound apart for bucketed tiles, else the one bucket (``_run``)
         ta0 = kc0 = None
-        if S.KERNEL_SEGMENT_SOFTMAX in self._kernels:
-            st = tiles.source if isinstance(tiles, BucketedTileSet) else tiles
-            ta0 = _tile_arrays(st)
-            kc0 = self._tile_const(st)
+        if (S.KERNEL_SEGMENT_SOFTMAX in self._kernels
+                and isinstance(tiles, BucketedTileSet)):
+            ta0 = _tile_arrays(tiles.source)
+            kc0 = self._tile_const(tiles.source)
         rel = None
         if self.n_types is not None:
             from ..kernels.relation.ops import layout_operands
@@ -355,11 +375,14 @@ class PipelinedRunner:
              rel) -> List[Array]:
         from ..kernels.relation.ops import relation_aggregate
         from ..kernels.tile_spmm.ops import (densify_edge_scores,
-                                             densify_edge_weights)
+                                             densify_edge_weights,
+                                             grid_edge_scores)
 
         sp = self.sp
         V = self.graph.n_vertices
         P, dmax = self.tiles.n_dst_parts, self.dmax
+        if ta0 is None:          # unbucketed tiles: one tile batch (``bind``)
+            ta0, kc0 = tas[0], kcs[0]
         # every op below is opened under one stage scope (``_stage``)
         with _stage("vertex"):
             pad_ids = jnp.asarray(self.part_ids_pad)      # (P, Dmax), V = invalid
@@ -462,11 +485,12 @@ class PipelinedRunner:
         def src_value(senv, nid, rows):
             return senv[nid] if nid in senv else vstore[nid][rows]
 
-        def weight_grid(g, senv, ta):
-            """A vertex-only edge weight on the dense (T, Dmax, Smax) tile
-            grid: ``recvSrc`` reads the tile's source rows (T, 1, Smax, d),
-            ``recvDst`` its partition's rows (T, Dmax, 1, d), and the
-            elementwise nodes broadcast them against each other."""
+        def edge_grid(g, senv, ta):
+            """A vertex-only edge weight or score (``_edge_result``) on the
+            dense (T, Dmax, Smax) tile grid: ``recvSrc`` reads the tile's
+            source rows (T, 1, Smax, d), ``recvDst`` its partition's rows
+            (T, Dmax, 1, d), and the elementwise nodes broadcast them
+            against each other.  Cells with no edge hold anything."""
             genv: Dict[int, Array] = {}
             for n in g.edge_nodes:
                 if n.op == "recvSrc":
@@ -479,7 +503,15 @@ class PipelinedRunner:
                 else:
                     genv[n.id] = apply_compute(n.op, n.attrs, params,
                                                [genv[i] for i in n.inputs])
-            return genv[g.weight_id][..., 0]
+            return genv[_edge_result(g)][..., 0]
+
+        def edge_count(ta):
+            """(T, Dmax, Smax) parallel-edge count of one tile batch.  Every
+            grid block calls it; XLA merges the calls into one scatter."""
+            return densify_edge_weights(
+                jnp.ones(ta["edge_src"].shape, jnp.float32), ta["edge_dst"],
+                ta["edge_src"], ta["n_edge"], dmax=dmax,
+                smax=int(ta["src_ids"].shape[1]))
 
         def unpad(val):
             """(P, Dmax, d) partition-padded -> (V, d) vertex store."""
@@ -531,27 +563,38 @@ class PipelinedRunner:
                             vstore[g.acc.recv_id] = out
                     continue
                 if g.kernel == S.KERNEL_SEGMENT_SOFTMAX:
-                    def tile_se(xs):
-                        senv = eval_vertex(xs["src_ids"], phase.src.nodes)
-                        _, elookup = edge_env(g.edge_nodes, xs, senv)
-                        h = src_value(senv, g.src_value_id, xs["src_ids"])
-                        return elookup(g.score_id)[:, 0], h[xs["edge_src"]]
+                    if g.acc.recv_id in self._on_grid:
+                        # scores on the (T, Dmax, Smax) grid, values per
+                        # source row (T, Smax, F): nothing per edge slot
+                        with _stage("edge"):
+                            senv = eval_vertex(ta0["src_ids"], phase.src.nodes)
+                            s = edge_grid(g, senv, ta0)
+                            vals = src_value(senv, g.src_value_id,
+                                             ta0["src_ids"])
+                        with _stage("densify"):
+                            scores = grid_edge_scores(s, edge_count(ta0))
+                    else:
+                        def tile_se(xs):
+                            senv = eval_vertex(xs["src_ids"], phase.src.nodes)
+                            _, elookup = edge_env(g.edge_nodes, xs, senv)
+                            h = src_value(senv, g.src_value_id, xs["src_ids"])
+                            return elookup(g.score_id)[:, 0], h[xs["edge_src"]]
 
-                    with _stage("edge"):
-                        scores_e, vals = jax.vmap(tile_se)(with_dst(ta0))
-                    if self.layout == "csr":
-                        # per-edge scores/vals feed the kernel directly: the
-                        # row-pointer walk replaces the densify pass
-                        with _stage("kernel"):
+                        with _stage("edge"):
+                            scores_e, vals = jax.vmap(tile_se)(with_dst(ta0))
+                        if self.layout != "csr":
+                            with _stage("densify"):
+                                scores = densify_edge_scores(
+                                    scores_e, ta0["edge_dst"], ta0["n_edge"],
+                                    dmax=dmax)
+                    with _stage("kernel"):
+                        if self.layout == "csr":
+                            # per-edge scores/vals feed the kernel directly:
+                            # the row-pointer walk replaces the densify pass
                             out = self.softmax_csr_kernel(
                                 ta0["row_ptr"], scores_e, vals, ta0["part_id"],
                                 kc0["flags"], n_parts=P)
-                    else:
-                        with _stage("densify"):
-                            scores = densify_edge_scores(
-                                scores_e, ta0["edge_dst"], ta0["n_edge"],
-                                dmax=dmax)
-                        with _stage("kernel"):
+                        else:
                             out = self.softmax_kernel(
                                 scores, vals, ta0["part_id"], kc0["flags"],
                                 n_parts=P)
@@ -565,7 +608,7 @@ class PipelinedRunner:
                 # partition outputs summed into a shared (P, Dmax, F) buffer
                 with _stage("vertex"):
                     total = jnp.zeros((P, dmax, g.acc.dim), jnp.float32)
-                on_grid = g.acc.recv_id in self._grid_weights
+                on_grid = g.acc.recv_id in self._on_grid
                 for ta, kc in zip(tas, kcs):
                     def tile_w(xs):
                         senv_t = eval_vertex(xs["src_ids"], phase.src.nodes)
@@ -578,7 +621,7 @@ class PipelinedRunner:
                         if g.kernel == S.KERNEL_SPMM:
                             w = None
                         elif on_grid:
-                            w = weight_grid(g, senv, ta)     # (T, Dmax, Smax)
+                            w = edge_grid(g, senv, ta)       # (T, Dmax, Smax)
                         else:
                             w = jax.vmap(tile_w)(with_dst(ta))     # (T, E)
                     if self.layout == "csr":
@@ -605,10 +648,7 @@ class PipelinedRunner:
                             # weights; a select, since off-edge grid cells
                             # may be inf or NaN and inf·0 is NaN
                             with _stage("densify"):
-                                cnt = densify_edge_weights(
-                                    jnp.ones(ta["edge_src"].shape, jnp.float32),
-                                    ta["edge_dst"], ta["edge_src"],
-                                    ta["n_edge"], dmax=dmax, smax=smax)
+                                cnt = edge_count(ta)
                                 adj = jnp.where(cnt > 0, cnt * w, 0.0)
                         else:    # weighted: densify the runtime edge weights
                             with _stage("densify"):

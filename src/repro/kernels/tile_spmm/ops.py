@@ -88,7 +88,9 @@ def densify_edge_scores(scores, edge_dst, n_edge, *, dmax: int):
     blocks where column ``j`` holds edge ``j``'s score at its destination row
     and the ``_NEG`` sentinel everywhere else.  Giving every edge its own
     column (instead of compacting onto source columns) keeps parallel edges
-    in separate softmax slots, so multigraphs stay exact.
+    in separate softmax slots, so multigraphs stay exact.  It serves only
+    the per-slot fallback (scores that read an edge input or a parameter):
+    a vertex-only score takes :func:`grid_edge_scores` on source columns.
     """
     T, E = scores.shape
     emask = jnp.arange(E)[None, :] < n_edge[:, None]
@@ -98,6 +100,19 @@ def densify_edge_scores(scores, edge_dst, n_edge, *, dmax: int):
         return jnp.full((dmax, E), _NEG, jnp.float32).at[ed, jnp.arange(E)].set(s_t)
 
     return jax.vmap(per_tile)(s, edge_dst)
+
+
+def grid_edge_scores(scores, cnt):
+    """Segment-softmax score block on the dense (T, dmax, smax) tile grid.
+
+    scores: a vertex-only score at every (destination, source) cell; cnt:
+    the tile's parallel-edge counts.  A cell's ``cnt`` parallel edges share
+    its score, and ``exp(s + log cnt) = cnt·exp(s)``, so one column per
+    source keeps multigraphs exact; where ``cnt`` is 1 the score is unchanged.
+    Cells with no edge are selected to the ``_NEG`` sentinel, never masked
+    by arithmetic: their grid score may be anything, inf or NaN included.
+    """
+    return jnp.where(cnt > 0, scores + jnp.log(cnt), _NEG)
 
 
 # Kernel entry points.  The backend decides the Pallas mode

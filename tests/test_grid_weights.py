@@ -1,15 +1,21 @@
-"""Vertex-only edge weights on the dense tile grid (``core/pipeline.py``).
+"""Vertex-only edge weights and scores on the dense tile grid
+(``core/pipeline.py``).
 
 A weighted-SpMM block whose edge weight reads only the values at each
 edge's two endpoints (stacked GCN's ``dn[src]·dn[dst]``) is evaluated once
 per tile on the dense ``(Dmax, Smax)`` grid and scaled by the tile's
-edge-count adjacency, instead of per padded edge slot.  Pinned here: the
-grid path matches the whole-graph reference and the per-slot path, stays
-finite where off-edge grid cells are not, engages exactly where the weight
-is vertex-only, and leaves no per-slot gather in the ``zipper.edge`` stage.
+edge-count adjacency, instead of per padded edge slot.  A segment-softmax
+block whose score is vertex-only (GAT's ``leaky_relu(es[src] + ed[dst])``)
+is evaluated on the same grid, shifted by ``log`` of the edge count and fed
+to the kernel with per-source-row values.  Pinned here: both grid paths
+match the whole-graph reference and the per-slot path, stay finite where
+off-edge grid cells are not, engage exactly where the weight or score is
+vertex-only, leave no per-slot gather in the ``zipper.edge`` stage, and
+share one count scatter per compiled forward, scoped to ``zipper.densify``.
 """
 import re
 
+import jax
 import numpy as np
 import pytest
 
@@ -87,7 +93,7 @@ def test_grid_weights_match_reference_and_per_slot(n_buckets, monkeypatch):
     assert grid.weight_paths == {"grid": 2, "edge": 0}
     out_grid = grid(inputs, params)[0]
 
-    monkeypatch.setattr(pipeline, "_vertex_only_weight", lambda blk: False)
+    monkeypatch.setattr(pipeline, "_vertex_only", lambda blk: False)
     slot = pipeline.PipelinedRunner(c, g, tiles, kernel_dispatch=True)
     assert slot.weight_paths == {"grid": 0, "edge": 2}
     out_slot = slot(inputs, params)[0]
@@ -174,6 +180,177 @@ def test_no_per_slot_gather_in_the_edge_stage(monkeypatch):
     assert sizes and T * emax not in sizes
 
     # the check bites: the per-slot path gathers one scalar per edge slot
-    monkeypatch.setattr(pipeline, "_vertex_only_weight", lambda blk: False)
+    monkeypatch.setattr(pipeline, "_vertex_only", lambda blk: False)
     slot = pipeline.PipelinedRunner(c, g, tiles, kernel_dispatch=True)
     assert T * emax in _edge_gather_sizes(slot, inputs, params)
+
+
+# ---- segment-softmax scores on the grid ------------------------------------
+
+def _gat():
+    tr = models.trace_stacked("gat", 2, DIM, DIM, DIM)
+    return tr, compiler.compile_gnn(tr)
+
+
+def _div_score_model():
+    """One softmax layer whose score divides by the destination's value:
+    ``out = Σ_e softmax_e(a[src] / b[dst]) · h[src]``."""
+    def build(tr, g):
+        x = tr.input_vertex(DIM, "x")
+        a = tr.input_vertex(1, "a")
+        b = tr.input_vertex(1, "b")
+        h = x.matmul(tr.param("W", (DIM, DIM)))
+        alpha = g.edge_softmax(g.scatter_src(a) / g.scatter_dst(b))
+        tr.mark_output(g.gather_sum(g.scatter_src(h) * alpha))
+    return trace_model(build, name="div_score")
+
+
+@pytest.mark.parametrize("n_buckets", [None, 2], ids=["plain", "bucketed"])
+def test_grid_scores_match_reference_and_per_slot(n_buckets, monkeypatch):
+    g = _graph()
+    tiles = _tiles(g, n_buckets=n_buckets)
+    tr, c = _gat()
+    params, inputs = models.init_params(tr, 1), models.init_inputs(tr, g, 1)
+    ref = executor.run_reference(tr, g, inputs, params)[0]
+
+    grid = pipeline.PipelinedRunner(c, g, tiles, kernel_dispatch=True)
+    assert grid.score_paths == {"grid": 2, "edge": 0}
+    out_grid = np.asarray(grid(inputs, params)[0])
+
+    monkeypatch.setattr(pipeline, "_vertex_only", lambda blk: False)
+    slot = pipeline.PipelinedRunner(c, g, tiles, kernel_dispatch=True)
+    assert slot.score_paths == {"grid": 0, "edge": 2}
+    out_slot = slot(inputs, params)[0]
+
+    assert np.all(np.isfinite(out_grid))
+    # destinations with no in-edge (the isolated vertices among them) read 0
+    isolated = g.in_degrees() == 0
+    assert np.sum(isolated) >= 7 and np.all(out_grid[isolated] == 0.0)
+    assert _rel(out_grid, ref) <= REL_TOL
+    assert _rel(out_grid, out_slot) <= REL_TOL
+
+
+def test_grid_score_division_by_zero_stays_finite():
+    """``b`` is 0 on the isolated vertices and on what padded destination
+    rows read: their grid scores are ±inf or NaN, which only a select keeps
+    out of the kernel's softmax."""
+    g = _graph()
+    tr = _div_score_model()
+    tiles = _tiles(g)
+    runner = pipeline.PipelinedRunner(compiler.compile_gnn(tr), g, tiles,
+                                      kernel_dispatch=True)
+    assert runner.score_paths == {"grid": 1, "edge": 0}
+
+    rng = np.random.default_rng(6)
+    V = g.n_vertices
+    b = rng.uniform(0.5, 2.0, (V, 1)).astype(np.float32)
+    b[g.in_degrees() == 0] = 0.0
+    assert b[V - 1, 0] == 0.0 and int(tiles.part_size.max()) * 3 > V
+    inputs = {"x": rng.standard_normal((V, DIM)).astype(np.float32),
+              "a": rng.standard_normal((V, 1)).astype(np.float32), "b": b}
+    params = {"W": rng.standard_normal((DIM, DIM)).astype(np.float32)}
+
+    out = np.asarray(runner(inputs, params)[0])
+    ref = np.asarray(executor.run_reference(tr, g, inputs, params)[0])
+    assert np.all(np.isfinite(ref))
+    assert np.all(np.isfinite(out))
+    assert _rel(out, ref) <= REL_TOL
+
+
+def _gat_naive(optimize):
+    tr = trace_model(lambda t, gr: models.build_gat_naive(t, gr, DIM, DIM),
+                     name="gat_naive")
+    return tr, compiler.compile_gnn(tr, optimize=optimize)
+
+
+@pytest.mark.parametrize("case,expected", [
+    ("gat-coo", {"grid": 2, "edge": 0}),
+    ("gat-csr", {"grid": 0, "edge": 2}),
+    # the compiler hoists gat_naive's attention mat-vecs to the vertices
+    ("gat_naive-coo", {"grid": 1, "edge": 0}),
+    # unoptimized, its score holds the per-edge gemv with a parameter
+    ("gat_naive-unoptimized-coo", {"grid": 0, "edge": 1}),
+    ("gcn-coo", {"grid": 0, "edge": 0}),
+])
+def test_score_paths_follow_the_program(case, expected):
+    g = _graph()
+    if case.startswith("gat_naive"):
+        tr, c = _gat_naive(optimize="unoptimized" not in case)
+    else:
+        tr = models.trace_stacked(case.split("-")[0], 2, DIM, DIM, DIM)
+        c = compiler.compile_gnn(tr)
+    tiles = _tiles(g, layout="csr" if case.endswith("csr") else "coo")
+    runner = pipeline.PipelinedRunner(c, g, tiles, kernel_dispatch=True)
+    assert runner.score_paths == expected
+    if case.startswith("gat_naive"):
+        params, inputs = models.init_params(tr, 2), models.init_inputs(tr, g, 2)
+        ref = executor.run_reference(tr, g, inputs, params)[0]
+        assert _rel(runner(inputs, params)[0], ref) <= REL_TOL
+
+
+def _shapes(jaxpr):
+    """Every (primitive, output shape) in a jaxpr and its sub-jaxprs, once
+    per call site."""
+    out = []
+    for eqn in jaxpr.eqns:
+        out += [(eqn.primitive.name, tuple(v.aval.shape))
+                for v in eqn.outvars if hasattr(v.aval, "shape")]
+        for p in eqn.params.values():
+            for sub in (p if isinstance(p, (list, tuple)) else [p]):
+                if isinstance(sub, jax.extend.core.ClosedJaxpr):
+                    out += _shapes(sub.jaxpr)
+                elif isinstance(sub, jax.extend.core.Jaxpr):
+                    out += _shapes(sub)
+    return out
+
+
+def _program_shapes(runner, inputs, params):
+    args = runner._args(inputs, params, None)
+    return _shapes(jax.make_jaxpr(runner._run)(*args).jaxpr)
+
+
+@pytest.mark.parametrize("n_buckets", [None, 2], ids=["plain", "bucketed"])
+def test_no_per_slot_work_in_the_gat_program(n_buckets, monkeypatch):
+    g = _graph()
+    tiles = _tiles(g, n_buckets=n_buckets)
+    st = tiles.source if n_buckets else tiles
+    T, emax = st.edge_src.shape
+    dmax = int(st.part_size.max())
+    assert emax not in (st.s_max, dmax, DIM)
+    per_slot = {T * emax, T * emax * DIM}      # a scalar or a row per slot
+    tr, c = _gat()
+    params, inputs = models.init_params(tr, 1), models.init_inputs(tr, g, 1)
+
+    grid = pipeline.PipelinedRunner(c, g, tiles, kernel_dispatch=True)
+    sizes = _edge_gather_sizes(grid, inputs, params)
+    assert sizes and not per_slot & set(sizes)
+    assert (T, dmax, emax) not in {s for _, s in
+                                   _program_shapes(grid, inputs, params)}
+
+    # the checks bite: the per-slot path gathers per edge slot and builds
+    # one kernel column per slot
+    monkeypatch.setattr(pipeline, "_vertex_only", lambda blk: False)
+    slot = pipeline.PipelinedRunner(c, g, tiles, kernel_dispatch=True)
+    assert per_slot <= set(_edge_gather_sizes(slot, inputs, params))
+    assert (T, dmax, emax) in {s for _, s in
+                               _program_shapes(slot, inputs, params)}
+
+
+@pytest.mark.parametrize("model", ["gat", "gcn"])
+def test_one_count_scatter_per_forward(model):
+    """Both layers' grid blocks read one edge-count scatter in the compiled
+    forward, and it stays under the ``zipper.densify`` scope."""
+    g = _graph()
+    tiles = _tiles(g)
+    tr, c = _gat() if model == "gat" else _gcn()
+    params, inputs = models.init_params(tr, 1), models.init_inputs(tr, g, 1)
+    runner = pipeline.PipelinedRunner(c, g, tiles, kernel_dispatch=True)
+    assert runner.score_paths["grid"] + runner.weight_paths["grid"] == 2
+    T, dmax = tiles.edge_src.shape[0], int(tiles.part_size.max())
+    grid = f"f32[{T},{dmax},{tiles.s_max}]"
+    hlo = runner._jitted.lower(*runner._args(inputs, params, None)) \
+        .compile().as_text()
+    scatters = [ln for ln in hlo.splitlines()
+                if " scatter(" in ln and f"= {grid}" in ln]
+    assert len(scatters) == 1
+    assert 'op_name="jit(_run)/zipper.densify/' in scatters[0]

@@ -3,8 +3,13 @@ v5e chip, no chip needed.
 
 Each tile case compiles one of the COO/CSR SpMM and segment-softmax kernels
 at F=128 for one chip of a ``v5e:2x2`` topology and asserts that the program
-holds a Mosaic kernel (``tpu_custom_call``); the relation kernel compiles at
-the R-GCN cell's shape and as one dense matrix.  Two kinds of tile shapes:
+holds a Mosaic kernel (``tpu_custom_call``).  The COO segment softmax
+compiles in both forms the runner calls: one column per edge slot and one
+per source on the tile grid (``segment_softmax_grid``).  The relation kernel
+compiles at the R-GCN cell's shape and as one dense matrix.  The runner's
+whole forward compiles too, for gcn and gat on tiny COO tiles, to check that
+the tile grid's edge-count scatter keeps its ``zipper.densify`` op name.  Two
+kinds of tile shapes for the kernels:
 
 * ``serving`` — a 16-graph request of ~64 V / 256 E graphs, canonicalized
   by :class:`~repro.serve.signature.ShapeRegistry` (``target_part=256``);
@@ -35,7 +40,8 @@ from repro.serve.signature import ShapeRegistry
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 F = 128
-KERNELS = ("spmm", "segment_softmax", "spmm_csr", "segment_softmax_csr")
+KERNELS = ("spmm", "segment_softmax", "spmm_csr", "segment_softmax_csr",
+           "segment_softmax_grid")
 
 
 @pytest.fixture(scope="module")
@@ -97,6 +103,8 @@ def _kernel_args(kernel, ts, sharding):
         return K.tile_spmm_pallas, (a((T, D, S)), a((T, S, F))) + meta
     if kernel == "segment_softmax":
         return K.segment_softmax_pallas, (a((T, D, E)), a((T, E, F))) + meta
+    if kernel == "segment_softmax_grid":
+        return K.segment_softmax_pallas, (a((T, D, S)), a((T, S, F))) + meta
     rp = a((T, D + 1), jnp.int32)
     if kernel == "spmm_csr":
         return K.tile_spmm_csr_pallas, (rp, a((T, E), jnp.int32), a((T, E)),
@@ -169,3 +177,39 @@ def test_relation_sum_kernel_compiles_for_v5e(one_chip, mosaic):
                                  a((T,), jnp.int32)).compile()
     assert f"%{RK.SUM_NAME}" in compiled.as_text()
     assert compiled.out_info.shape == (P, S, W)
+
+
+@pytest.mark.parametrize("model", ["gcn", "gat"])
+def test_count_scatter_keeps_its_scope_on_v5e(model, one_chip, mosaic):
+    """The tile grid's edge-count scatter, called by both layers' grid
+    blocks, compiles for the chip to one fusion that keeps the
+    ``zipper.densify`` op name the device trace attributes stages by."""
+    import re
+
+    from repro.core import compiler, pipeline
+    from repro.gnn import models
+
+    g = graphs.random_graph(300, 1500, seed=1, model="powerlaw")
+    ts, _ = tiling.build_tiles(g, 4, 4)
+    tr = models.trace_stacked(model, 2, 16, 16, 16)
+    runner = pipeline.PipelinedRunner(compiler.compile_gnn(tr), g, ts,
+                                      kernel_dispatch=True)
+    assert runner.weight_paths["grid"] + runner.score_paths["grid"] == 2
+    args = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        runner._args(models.init_inputs(tr, g, 1),
+                     models.init_params(tr, 1), None))
+    text = runner._jitted.lower(*args).compile().as_text()
+
+    cells = ts.n_tiles * int(ts.part_size.max()) * ts.s_max
+    comp, holder = None, []           # fused computations holding the count
+    for ln in text.splitlines():
+        if ln and not ln[0].isspace() and ln.rstrip().endswith("{"):
+            comp = ln.split()[0].lstrip("%")
+        elif " scatter(" in ln and f"= f32[{cells}]" in ln:
+            holder.append(comp)
+    assert len(holder) == 1
+    calls = [ln for ln in text.splitlines()
+             if re.search(rf"calls=%?{re.escape(holder[0])}\b", ln)]
+    assert len(calls) == 1
+    assert 'op_name="jit(_run)/zipper.densify/' in calls[0]
