@@ -7,7 +7,6 @@ One module per paper table/figure (DESIGN.md §1):
   bench_e2v      — Fig 12  compiler (E2V) optimization
   bench_streams  — Fig 13  stream/unit design-space exploration
   bench_area     — Table 5 area model
-  roofline       — §Roofline terms for the LM cells (reads reports/dryrun)
 """
 from __future__ import annotations
 
@@ -26,11 +25,10 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     from . import (bench_area, bench_e2v, bench_memory, bench_speedup,
-                   bench_streams, bench_tiling, perf_report, roofline)
+                   bench_streams, bench_tiling)
     benches = {
         "memory": bench_memory, "speedup": bench_speedup, "tiling": bench_tiling,
         "e2v": bench_e2v, "streams": bench_streams, "area": bench_area,
-        "roofline": roofline, "perf": perf_report,
     }
     selected = args.only.split(",") if args.only else list(benches)
     failures = 0

@@ -8,10 +8,11 @@ and (b) the fused Pallas tile kernels (``kernels/tile_spmm`` +
 ``kernels/segment_softmax``), whose grid pipelining double-buffers the
 HBM→VMEM DMA against the MXU.
 
-This module is the scan-based engine: one jit-compiled function per
-(compiled model × tile-set shape).  Like ``executor.run_tiled`` it is an
-*interpreter* of the :class:`~repro.core.schedule.ScheduledProgram` — it
-derives no levels or roles of its own.  Per phase:
+This module is the single-chip engine (``PipelinedRunner``) and its
+sharded twin (``ShardedRunner``): one jit-compiled function per (compiled
+model × tile-set shape), checked against ``executor.run_reference``.  Each
+is an *interpreter* of the :class:`~repro.core.schedule.ScheduledProgram` —
+it derives no levels or roles of its own.  Per phase:
 
 * the destination block runs vectorized over partitions,
 * gather blocks tagged ``pallas_spmm`` / ``pallas_spmm_weighted`` dispatch

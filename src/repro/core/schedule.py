@@ -4,9 +4,10 @@ ZIPPER's compiler lowers graph-native IR into a *schedule* that a run-time
 scheduler maps onto dedicated hardware blocks.  This module is that layer:
 :func:`lower` turns an :class:`~repro.core.compiler.SDEPlan` into an explicit
 :class:`ScheduledProgram` — per gather level one :class:`Phase` of typed
-blocks — and every engine (``executor.run_tiled``, ``pipeline.PipelinedRunner``,
-``isa.emit_sde`` + the cycle simulator) *interprets* the same program instead
-of re-deriving levels and roles on its own.
+blocks — and every engine (``pipeline.PipelinedRunner``,
+``pipeline.ShardedRunner``, ``isa.emit_sde`` + the cycle simulator)
+*interprets* the same program instead of re-deriving levels and roles on its
+own.
 
 Blocks per phase:
 

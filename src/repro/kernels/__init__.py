@@ -1,11 +1,10 @@
 """Pallas TPU kernels (pl.pallas_call + BlockSpec) with jnp oracles.
 
-  flash_attention/  blocked online-softmax attention (train/prefill/decode)
-  moe_dispatch/     capacity-bucket grouped FFN (ZIPPER tiling over tokens)
   tile_spmm/        block-dense SpMM over graph tiles (the paper's dataflow)
-  segment_softmax/  GAT edge softmax, single-pass online variant
+                    and the GAT online segment softmax (kernel.py, ops.py,
+                    ref.py)
+  segment_softmax/  re-exports the segment-softmax entry points of tile_spmm
   relation/         R-GCN typed aggregation: relation-grouped transform and
                     destination sum (kernel.py, ops.py; the oracle is
                     ``core.executor.typed_transform``)
-Each provides kernel.py (Pallas), ops.py (jit wrapper), ref.py (oracle).
 """

@@ -5,9 +5,8 @@ rather than fixed).
 Searches the tile-config lattice — grid (``n_dst_parts`` x ``n_src_parts``)
 x ``n_buckets`` x shard count x vertex ``reorder`` (identity / degree,
 paper §5.3) x within-tile edge ``layout`` (COO / CSR) — for one compiled
-program over a representative graph of a class.  The harness repurposes the
-``launch/hillclimb.py`` pattern (variant -> scored JSON-able record,
-deltas against a baseline) for this lattice:
+program over a representative graph of a class.  Each variant becomes a
+scored JSON-able record, with deltas against a baseline:
 
 1. the *cheap objective* is :func:`~repro.core.simulator.simulate_sharded`'s
    padded cost model over the **kernel-dispatch** schedule (``padded=True``
@@ -23,9 +22,8 @@ deltas against a baseline) for this lattice:
    provenance — the serving engine consults the cache per size class and
    routes large requests onto the tuned config.
 
-Pure library: no XLA flags are touched at import (unlike the dryrun
-hillclimb driver, which forces a 512-device host platform), so it is safe
-to import from tests and the serving engine.
+Pure library: no XLA flags are touched at import, so it is safe to import
+from tests and the serving engine.
 """
 from __future__ import annotations
 

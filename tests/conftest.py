@@ -1,20 +1,11 @@
 import os
 import sys
 
-# smoke tests and benches must see ONE device (the dry-run sets its own
-# 512-device flag in its own process) — do not set device-count flags here.
+# tests and benches must see ONE device — do not set device-count flags here.
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import numpy as np
 import pytest
-
-import jax
-
-
-@pytest.fixture(scope="session")
-def host_mesh():
-    from repro.launch.mesh import make_host_mesh
-    return make_host_mesh()
 
 
 @pytest.fixture()

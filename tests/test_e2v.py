@@ -2,7 +2,7 @@
 import jax.numpy as jnp
 import pytest
 
-from repro.core import compiler, executor, isa, tiling
+from repro.core import compiler, isa, pipeline, tiling
 from repro.gnn import graphs, models
 
 
@@ -46,8 +46,10 @@ def test_e2v_numerically_equivalent(name):
     ts = tiling.grid_tile(g, 3, 3)
     c_opt = compiler.compile_gnn(tr, optimize=True)
     c_naive = compiler.compile_gnn(tr, optimize=False)
-    o1 = executor.run_tiled(c_opt, g, ts, inputs, params)
-    o2 = executor.run_tiled(c_naive, g, ts, inputs, params)
+    o1 = pipeline.run_pipelined(c_opt, g, ts, inputs, params,
+                                kernel_dispatch=True)
+    o2 = pipeline.run_pipelined(c_naive, g, ts, inputs, params,
+                                kernel_dispatch=True)
     for a, b in zip(o1, o2):
         assert float(jnp.max(jnp.abs(a - b))) < 1e-4
 

@@ -1,10 +1,11 @@
 """Integration: compiled+tiled+pipelined execution ≡ whole-graph oracle for
-all five paper models, across tiling strategies and reordering."""
+the paper models and gin, across tiling strategies (vertex order and edge
+layout: ``test_runner_conformance.py``)."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import compiler, executor, pipeline, reorder, tiling
+from repro.core import compiler, executor, pipeline, tiling
 from repro.gnn import graphs, models
 from repro.kernels.tile_spmm import ops as tops
 
@@ -27,9 +28,10 @@ def _run_all(name, g, strategy):
     if strategy == "bucketed+kernel":
         tile_kernel = tops.spmm
     if strategy in ("regular", "sparse"):
-        out_tiled = executor.run_tiled(c, g, ts, inputs, params)
-        for a, b in zip(ref, out_tiled):
-            assert float(jnp.max(jnp.abs(a - b))) < TOL, "tiled != oracle"
+        out_kernel = pipeline.run_pipelined(c, g, ts, inputs, params,
+                                            kernel_dispatch=True)
+        for a, b in zip(ref, out_kernel):
+            assert float(jnp.max(jnp.abs(a - b))) < TOL, "kernel != oracle"
     out_pipe = pipeline.run_pipelined(c, g, ts, inputs, params,
                                       tile_kernel=tile_kernel)
     for a, b in zip(ref, out_pipe):
@@ -56,26 +58,6 @@ def test_kernel_engages_on_pure_spmm_models():
         assert (schedule.KERNEL_SPMM in kernels) == engaged, name
 
 
-@pytest.mark.parametrize("name", ["gcn", "gat"])
-def test_with_reordering(name):
-    g0 = graphs.random_graph(200, 800, seed=4, model="powerlaw", n_edge_types=3)
-    r = reorder.degree_sort(g0)
-    tr = models.trace_named(name, 16, 16)
-    c = compiler.compile_gnn(tr)
-    params = models.init_params(tr)
-    inputs0 = models.init_inputs(tr, g0)
-    # oracle on the ORIGINAL graph
-    ref = executor.run_reference(tr, g0, inputs0, params)
-    # tiled on the REORDERED graph with permuted inputs, outputs un-permuted
-    inputs1 = {k: (r.permute_vertex_features(v) if v.shape[0] == g0.n_vertices else v)
-               for k, v in inputs0.items()}
-    ts = tiling.grid_tile(r.graph, 4, 4, sparse=True)
-    out = executor.run_tiled(c, r.graph, ts, inputs1, params)
-    for a, b in zip(ref, out):
-        b_unperm = r.unpermute_vertex_outputs(np.asarray(b))
-        assert float(jnp.max(jnp.abs(a - b_unperm))) < TOL
-
-
 def test_empty_partition_handled():
     # a graph whose high partitions have no in-edges
     src = np.array([0, 1, 2, 3], np.int32)
@@ -87,7 +69,8 @@ def test_empty_partition_handled():
     inputs = models.init_inputs(tr, g)
     ts = tiling.grid_tile(g, 4, 4)
     ref = executor.run_reference(tr, g, inputs, params)
-    out = executor.run_tiled(c, g, ts, inputs, params)
+    out = pipeline.run_pipelined(c, g, ts, inputs, params,
+                                 kernel_dispatch=True)
     assert float(jnp.max(jnp.abs(ref[0] - out[0]))) < TOL
 
 

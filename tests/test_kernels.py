@@ -1,136 +1,14 @@
-"""Per-kernel shape/dtype sweeps: Pallas (interpret mode) vs jnp oracles."""
+"""Tile SpMM and segment-softmax kernels: Pallas (interpret mode) vs jnp oracles."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.flash_attention.kernel import flash_attention_pallas
-from repro.kernels.flash_attention.ops import flash_attention
-from repro.kernels.flash_attention.ref import attention_ref
-from repro.kernels.moe_dispatch import ops as mops
-from repro.kernels.moe_dispatch import ref as mref
 from repro.kernels.tile_spmm import ops as tops
 from repro.kernels.tile_spmm.ref import segment_softmax_ref, tile_spmm_ref
 from repro.core import tiling
 from repro.gnn import graphs
 
-
-# ---------------------------------------------------------------------------
-# flash attention
-# ---------------------------------------------------------------------------
-
-FLASH_SHAPES = [
-    # B, Sq, Sk, H, K, D, causal, window
-    (2, 64, 64, 4, 2, 32, True, None),
-    (1, 128, 128, 8, 8, 64, True, None),
-    (2, 33, 97, 6, 3, 16, True, None),      # ragged, GQA
-    (1, 64, 64, 4, 4, 32, False, None),     # bidirectional (whisper enc)
-    (2, 128, 128, 4, 2, 32, True, 48),      # sliding window (zamba)
-    (1, 1, 256, 8, 2, 64, True, None),      # decode
-]
-
-
-@pytest.mark.parametrize("B,Sq,Sk,H,K,D,causal,window", FLASH_SHAPES)
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_flash_scan_vs_ref(B, Sq, Sk, H, K, D, causal, window, dtype, rng):
-    q = jnp.asarray(rng.standard_normal((B, Sq, H, D)), dtype)
-    k = jnp.asarray(rng.standard_normal((B, Sk, K, D)), dtype)
-    v = jnp.asarray(rng.standard_normal((B, Sk, K, D)), dtype)
-    ref = attention_ref(q, k, v, causal=causal, window=window)
-    out = flash_attention(q, k, v, causal=causal, window=window, block_k=32)
-    tol = 2e-5 if dtype == jnp.float32 else 2e-2
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(ref, np.float32), atol=tol, rtol=tol)
-
-
-@pytest.mark.parametrize("B,Sq,Sk,H,K,D,causal,window", FLASH_SHAPES)
-def test_flash_pallas_vs_ref(B, Sq, Sk, H, K, D, causal, window, rng):
-    q = jnp.asarray(rng.standard_normal((B, Sq, H, D)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((B, Sk, K, D)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((B, Sk, K, D)), jnp.float32)
-    ref = attention_ref(q, k, v, causal=causal, window=window)
-    out = flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                 block_q=16, block_k=32)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
-
-
-def test_flash_mla_asymmetric_v_dim(rng):
-    """MLA: qk head dim 48, v head dim 32."""
-    q = jnp.asarray(rng.standard_normal((2, 16, 8, 48)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((2, 16, 8, 48)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((2, 16, 8, 32)), jnp.float32)
-    out = flash_attention(q, k, v, causal=True, block_k=8)
-    # oracle with explicit softmax
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * 48 ** -0.5
-    mask = jnp.tril(jnp.ones((16, 16), bool))
-    s = jnp.where(mask, s, -jnp.inf)
-    p = jax.nn.softmax(s, -1)
-    ref = jnp.einsum("bhqk,bkhd->bqhd", p, v)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
-
-
-def test_flash_kv_len_masking(rng):
-    q = jnp.asarray(rng.standard_normal((3, 1, 4, 32)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((3, 64, 2, 32)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((3, 64, 2, 32)), jnp.float32)
-    kvlen = jnp.array([10, 64, 33], jnp.int32)
-    ref = attention_ref(q, k, v, causal=True, kv_len=kvlen)
-    out = flash_attention(q, k, v, causal=False, block_k=16, kv_len=kvlen)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
-
-
-# ---------------------------------------------------------------------------
-# MoE dispatch / grouped FFN
-# ---------------------------------------------------------------------------
-
-MOE_SHAPES = [  # T, d, E, f, k
-    (64, 32, 8, 48, 2),
-    (128, 16, 16, 16, 1),
-    (96, 24, 4, 64, 4),
-]
-
-
-@pytest.mark.parametrize("T,d,E,f,k", MOE_SHAPES)
-@pytest.mark.parametrize("use_pallas", [False, True])
-def test_moe_matches_dense_oracle(T, d, E, f, k, use_pallas, rng):
-    x = jnp.asarray(rng.standard_normal((T, d)), jnp.float32)
-    rw = jnp.asarray(rng.standard_normal((d, E)) * 0.1, jnp.float32)
-    wg = jnp.asarray(rng.standard_normal((E, d, f)) / np.sqrt(d), jnp.float32)
-    wu = jnp.asarray(rng.standard_normal((E, d, f)) / np.sqrt(d), jnp.float32)
-    wd = jnp.asarray(rng.standard_normal((E, f, d)) / np.sqrt(f), jnp.float32)
-    ref = mref.moe_ref(x, rw, wg, wu, wd, top_k=k)
-    y, aux = mops.moe_block(x, rw, wg, wu, wd, top_k=k, capacity=T * k,
-                            use_pallas=use_pallas)
-    np.testing.assert_allclose(np.asarray(y), np.asarray(ref), atol=2e-5, rtol=2e-4)
-    assert float(aux) > 0
-
-
-def test_moe_capacity_drop_is_masked(rng):
-    """Over-capacity assignments are dropped, never mis-routed."""
-    T, d, E, f, k = 64, 16, 4, 16, 2
-    x = jnp.asarray(rng.standard_normal((T, d)), jnp.float32)
-    rw = jnp.asarray(rng.standard_normal((d, E)), jnp.float32)
-    wg = jnp.asarray(rng.standard_normal((E, d, f)) / 4, jnp.float32)
-    wu = jnp.asarray(rng.standard_normal((E, d, f)) / 4, jnp.float32)
-    wd = jnp.asarray(rng.standard_normal((E, f, d)) / 4, jnp.float32)
-    y, _ = mops.moe_block(x, rw, wg, wu, wd, top_k=k, capacity=4)
-    assert bool(jnp.isfinite(y).all())
-
-
-def test_route_counts_and_positions(rng):
-    x = jnp.asarray(rng.standard_normal((40, 8)), jnp.float32)
-    rw = jnp.asarray(rng.standard_normal((8, 4)), jnp.float32)
-    r = mops.route(x, rw, top_k=2, capacity=100)
-    assert int(r.counts.sum()) == 80  # T*k assignments
-    assert bool(r.keep.all())          # capacity ample -> nothing dropped
-    # bucket indices unique among kept assignments
-    b = np.asarray(r.bucket_idx)
-    assert len(np.unique(b)) == len(b)
-
-
-# ---------------------------------------------------------------------------
-# tile SpMM + segment softmax
-# ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("V,E,p,s,F", [(120, 500, 4, 4, 16), (80, 200, 2, 5, 8),
                                        (50, 600, 6, 2, 32)])

@@ -2,9 +2,9 @@
 cross-layer ScheduledProgram.
 
 Pinned here: (a) ``trace_model`` accepts stacked layer builders and tags
-every node with its layer; (b) all five paper models run stacked through all
-three engines (run_tiled, PipelinedRunner, emit_sde + simulator) and the JAX
-engines match a whole-graph *layer-by-layer* oracle; (c) the cross-layer
+every node with its layer; (b) all five paper models run stacked through
+PipelinedRunner, which matches a whole-graph *layer-by-layer* oracle, and
+through emit_sde + the simulator; (c) the cross-layer
 CSE pass removes repeated structure-only ops on stacked GCN and E2V hoists
 across layer boundaries; (d) the pipelined inter-layer schedule simulates
 fewer cycles than the barrier schedule; (e) program signatures distinguish
@@ -110,7 +110,7 @@ def test_scheduled_phases_carry_layer_tags():
 
 
 # ---------------------------------------------------------------------------
-# acceptance: five paper models, stacked, three engines, one oracle
+# acceptance: five paper models, stacked, two engines, one oracle
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("name", models.PAPER_MODELS)
@@ -130,13 +130,12 @@ def test_stacked_models_match_layer_by_layer_oracle(name, n_layers):
     ts = tiling.grid_tile(g, 4, 4, sparse=True)
     bt = tiling.bucket_tiles(ts, 3)
     for kd in (False, True):
-        out_t = executor.run_tiled(c, g, ts, inputs, params, kernel_dispatch=kd)
-        assert _rel_err(oracle, out_t[0]) < REL_TOL, (name, "run_tiled", kd)
-        out_p = pipeline.run_pipelined(c, g, bt, inputs, params,
-                                       kernel_dispatch=kd)
-        assert _rel_err(oracle, out_p[0]) < REL_TOL, (name, "pipelined", kd)
+        for tiles, kind in ((ts, "plain"), (bt, "bucketed")):
+            out = pipeline.run_pipelined(c, g, tiles, inputs, params,
+                                         kernel_dispatch=kd)
+            assert _rel_err(oracle, out[0]) < REL_TOL, (name, kind, kd)
 
-    # third engine: the multi-layer program lowers to SDE instructions and
+    # second engine: the multi-layer program lowers to SDE instructions and
     # executes through the cycle simulator in ONE pass (both schedules)
     for kd in (False, True):
         r = simulator.simulate_model(isa.emit_sde(c.schedule(kd)), ts)
@@ -199,7 +198,8 @@ def test_e2v_hoists_across_layer_boundaries():
     inputs = models.init_inputs(tr, g)
     ref = executor.run_reference(tr, g, inputs, params)
     ts = tiling.grid_tile(g, 3, 3, sparse=True)
-    out = executor.run_tiled(c, g, ts, inputs, params)
+    out = pipeline.run_pipelined(c, g, ts, inputs, params,
+                                 kernel_dispatch=True)
     assert _rel_err(ref[0], out[0]) < REL_TOL
 
 

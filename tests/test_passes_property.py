@@ -9,7 +9,7 @@ hypothesis (the pinned stacked-GCN dedupe count lives in
 import numpy as np
 import pytest
 
-from repro.core import compiler, executor, passes
+from repro.core import compiler, executor, passes, pipeline
 from repro.gnn import graphs, models
 
 REL_TOL = 1e-5   # pass round-trips re-run identical float ops
@@ -59,7 +59,8 @@ def _check_optimize_roundtrip(name, n_layers, dim, seed):
     ref = executor.run_reference(tr, g, inputs, params)
     from repro.core import tiling
     ts = tiling.grid_tile(g, 3, 3, sparse=True)
-    out = executor.run_tiled(c, g, ts, inputs, params, kernel_dispatch=False)
+    out = pipeline.run_pipelined(c, g, ts, inputs, params,
+                                 kernel_dispatch=False)
     for a, b in zip(ref, out):
         assert _rel_err(a, b) < 1e-4
 

@@ -89,9 +89,6 @@ def test_runner_and_oracle_match_the_equation(n_layers, dim, n_blocks,
     assert rows["real_rows"] == g.n_edges
     assert rows["relation_groups"] == N_TYPES - 1
 
-    tiled = executor.run_tiled(c, g, ts, inputs, params, kernel_dispatch=True)
-    assert _rel_err(tiled[0], want) < REL_TOL
-
 
 def test_published_sizes_schedule_no_scan_gather():
     """The FB15k-237 encoder (474 relations, 100 blocks of 5x5, width 500,

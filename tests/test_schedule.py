@@ -1,11 +1,12 @@
-"""Scheduler-layer tests (ISSUE 2): one lowering, three engines.
+"""Scheduler-layer tests: one lowering, every engine interprets it.
 
 The :mod:`repro.core.schedule` pass is the single source of truth for phase
-structure and kernel-block dispatch; both JAX engines interpret it, and the
-ISA codegen costs it.  These tests pin (a) engine parity against the
-whole-graph oracle on all five paper models under both dispatch modes,
-(b) the kernel tags the pattern matcher must pick, and (c) that the engines
-really do contain no level/role derivation of their own.
+structure and kernel-block dispatch; the JAX engines interpret it, and the
+ISA codegen costs it.  These tests pin (a) the kernel tags the pattern
+matcher must pick, (b) kernel-dispatched blocks against the whole-graph
+oracle, and (c) that the engines really do contain no level/role derivation
+of their own.  The engine × option parity matrix against the oracle is
+``test_runner_conformance.py``.
 """
 import inspect
 
@@ -22,30 +23,6 @@ def _compiled(name, dim=24):
     tr = models.trace_named(name, dim, dim)
     c = compiler.compile_gnn(tr)
     return tr, c
-
-
-@pytest.mark.parametrize("name", models.PAPER_MODELS)
-@pytest.mark.parametrize("kernel_dispatch", [False, True])
-def test_both_engines_match_oracle(name, kernel_dispatch):
-    """run_tiled and PipelinedRunner interpret the same ScheduledProgram and
-    agree with run_reference, with and without Pallas kernel dispatch."""
-    g = graphs.random_graph(180, 750, seed=3, model="powerlaw", n_edge_types=3)
-    tr, c = _compiled(name)
-    params = models.init_params(tr)
-    inputs = models.init_inputs(tr, g)
-    ref = executor.run_reference(tr, g, inputs, params)
-
-    ts = tiling.grid_tile(g, 4, 4, sparse=True)
-    out_tiled = executor.run_tiled(c, g, ts, inputs, params,
-                                   kernel_dispatch=kernel_dispatch)
-    for a, b in zip(ref, out_tiled):
-        assert float(jnp.max(jnp.abs(a - b))) < TOL, "run_tiled != oracle"
-
-    bt = tiling.bucket_tiles(ts, 3)
-    out_pipe = pipeline.run_pipelined(c, g, bt, inputs, params,
-                                      kernel_dispatch=kernel_dispatch)
-    for a, b in zip(ref, out_pipe):
-        assert float(jnp.max(jnp.abs(a - b))) < TOL, "pipelined != oracle"
 
 
 def test_gcn_aggregation_selects_pallas_spmm():
@@ -89,15 +66,13 @@ def test_gat_fused_softmax_matches_reference_tightly():
     ref = executor.run_reference(tr, g, inputs, params)
     ts = tiling.grid_tile(g, 4, 4, sparse=True)
     assert c.schedule(True).gather_kernel(0) == schedule.KERNEL_SEGMENT_SOFTMAX
-    out_t = executor.run_tiled(c, g, ts, inputs, params, kernel_dispatch=True)
-    out_p = pipeline.run_pipelined(c, g, ts, inputs, params,
-                                   kernel_dispatch=True)
-    for out in (out_t, out_p):
-        assert float(jnp.max(jnp.abs(ref[0] - out[0]))) < 1e-4
+    out = pipeline.run_pipelined(c, g, ts, inputs, params,
+                                 kernel_dispatch=True)
+    assert float(jnp.max(jnp.abs(ref[0] - out[0]))) < 1e-4
 
 
 def test_engines_have_no_phase_derivation():
-    """Acceptance: neither engine consults plan.level / plan.role — block
+    """Acceptance: no engine consults plan.level / plan.role — block
     membership comes entirely from schedule.lower."""
     for mod in (executor, pipeline):
         src = inspect.getsource(mod)
@@ -137,7 +112,7 @@ def test_simulator_runs_kernel_schedule():
 
 def test_edge_feature_weighted_gather_dispatches_and_runs():
     """recvSrc * w_e -> sendDstSum with a per-edge INPUT weight must select
-    the weighted-SpMM block, and both engines must evaluate it (edge inputs
+    the weighted-SpMM block, and the runner must evaluate it (edge inputs
     are read lazily, never fed to apply_compute)."""
     from repro.core.trace import trace_model
 
@@ -155,16 +130,15 @@ def test_edge_feature_weighted_gather_dispatches_and_runs():
     inputs = models.init_inputs(tr, g)
     ref = executor.run_reference(tr, g, inputs, params)
     ts = tiling.grid_tile(g, 3, 3, sparse=True)
-    out_t = executor.run_tiled(c, g, ts, inputs, params, kernel_dispatch=True)
-    out_p = pipeline.run_pipelined(c, g, ts, inputs, params,
-                                   kernel_dispatch=True)
-    for out in (out_t, out_p):
-        assert float(jnp.max(jnp.abs(ref[0] - out[0]))) < TOL
+    out = pipeline.run_pipelined(c, g, ts, inputs, params,
+                                 kernel_dispatch=True)
+    assert float(jnp.max(jnp.abs(ref[0] - out[0]))) < TOL
 
 
 def test_multigraph_parallel_edges_stay_exact():
-    """Per-edge-column score densification keeps parallel edges in separate
-    softmax slots — GAT on a multigraph still matches the oracle."""
+    """Parallel edges keep their weight in the softmax (on the tile grid a
+    tile's ``cnt`` equal scores add ``log cnt``) — GAT on a multigraph still
+    matches the oracle."""
     import numpy as np
 
     src = np.array([0, 0, 0, 1, 2, 2, 3, 3], np.int32)  # two (0->4), two (3->5)
@@ -175,5 +149,6 @@ def test_multigraph_parallel_edges_stay_exact():
     inputs = models.init_inputs(tr, g)
     ref = executor.run_reference(tr, g, inputs, params)
     ts = tiling.grid_tile(g, 2, 2, sparse=True)
-    out = executor.run_tiled(c, g, ts, inputs, params, kernel_dispatch=True)
+    out = pipeline.run_pipelined(c, g, ts, inputs, params,
+                                 kernel_dispatch=True)
     assert float(jnp.max(jnp.abs(ref[0] - out[0]))) < 1e-4
