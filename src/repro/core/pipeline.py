@@ -29,12 +29,15 @@ or score reads only its edge's endpoint vertex values (GCN's
 ``dn[src]·dn[dst]``, GAT's ``leaky_relu(es[src] + ed[dst])``) evaluates
 it once on the dense ``(T, Dmax, Smax)`` tile grid, a destination column
 broadcast against a source row, instead of gathering endpoint values per
-padded edge slot.  The tile's edge count, one scatter per tile batch in
-the compiled forward, then scales a grid weight (``cnt·w``) or shifts a grid score
-(``s + log cnt``: ``cnt`` parallel edges of equal score), and the softmax
-kernel reads the tile's source rows as its values.  The per-slot path, with
-its per-edge-column score densify, remains for CSR tiles and for weights
-or scores that read an edge input or a parameter.
+padded edge slot.  The tile's edge count then scales a grid weight
+(``cnt·w``) or shifts a grid score (``s + log cnt``: ``cnt`` parallel edges
+of equal score), and the softmax kernel reads the tile's source rows as its
+values.  The count depends only on the tiles: ``bind`` builds it once per
+tile batch with one device scatter (``tile_spmm.ops.edge_count``) and
+binds it next to the batch's kernel flags, so the compiled forward only
+reads it.  Pure SpMM's adjacency is the same count.  The per-slot path,
+with its per-edge-column score densify, remains for CSR tiles and for
+weights or scores that read an edge input or a parameter.
 
 A gather block tagged ``pallas_relation`` (R-GCN's typed aggregation) runs
 over the tile set's relation layout (``tiling.relation_layout``, built at
@@ -87,6 +90,17 @@ def _tile_arrays(ts: TileSet) -> Dict[str, Array]:
     if ts.row_ptr is not None:
         d["row_ptr"] = jnp.asarray(ts.row_ptr)
     return d
+
+
+#: key of a tile batch's edge count among its kernel constants
+_COUNT = "cnt"
+
+
+def host_leaves(operands) -> List[Array]:
+    """The leaves of :meth:`PipelinedRunner.bind`'s operands that were
+    copied from the host: all but the edge counts, built on the device."""
+    return [a for path, a in jax.tree_util.tree_leaves_with_path(operands)
+            if path[-1] != jax.tree_util.DictKey(_COUNT)]
 
 
 def _perm_operand(reordering) -> Optional[Dict[str, Array]]:
@@ -255,6 +269,14 @@ class PipelinedRunner:
         #: on the dense (T, Dmax, Smax) tile grid, ``edge`` per padded slot
         self.weight_paths = paths(weighted)
         self.score_paths = paths(softmax)
+        # which tile batches a kernel block reads the edge count of: each
+        # bucket (grid weights, pure SpMM's adjacency over COO tiles), the
+        # softmax batch (grid scores)
+        self._count_buckets = bool(self.weight_paths["grid"]) or (
+            S.KERNEL_SPMM in self._kernels and self.layout != "csr")
+        self._count_source = bool(self.score_paths["grid"])
+        #: tile batches whose edge count the last :meth:`bind` built
+        self.bound_counts = 0
         nodes = {n.id: n for seg in self.sp.prog.segments
                  for n in seg.nodes.values()}
         bmms = {g.acc.recv_id: nodes[g.bmm_id] for ph in self.sp.phases
@@ -291,22 +313,21 @@ class PipelinedRunner:
             return -1
 
     # ------------------------------------------------------------- constants
-    def _tile_const(self, ts: TileSet) -> Dict[str, Array]:
-        """FIRST/LAST flags + partition presence mask for one tile batch."""
+    def _tile_const(self, ts: TileSet, ta: Dict[str, Array],
+                    with_count: bool) -> Dict[str, Array]:
+        """FIRST/LAST flags + partition presence mask for one tile batch,
+        and with ``with_count`` its (T, Dmax, Smax) edge count, scattered
+        on the device from the batch's bound tile arrays ``ta``."""
         from ..kernels.tile_spmm.kernel import tile_flags
+        from ..kernels.tile_spmm.ops import edge_count
         P = self.tiles.n_dst_parts
-        return dict(flags=jnp.asarray(tile_flags(ts.part_id)),
-                    pmask=jnp.asarray(np.isin(np.arange(P), ts.part_id)
-                                      .astype(np.float32)))
-
-    def _bucket_const(self, b: TileSet, with_adj: bool) -> Dict[str, Array]:
-        """Per-bucket kernel metadata; dense adjacency only for pure SpMM
-        over COO tiles (CSR kernels walk row pointers instead)."""
-        from ..kernels.tile_spmm.ops import densify_tiles
-        kc = self._tile_const(b)
-        if with_adj and b.layout != "csr":
-            adj, _ = densify_tiles(b)
-            kc["adj"] = jnp.asarray(adj)
+        kc = dict(flags=jnp.asarray(tile_flags(ts.part_id)),
+                  pmask=jnp.asarray(np.isin(np.arange(P), ts.part_id)
+                                    .astype(np.float32)))
+        if with_count:
+            kc[_COUNT] = edge_count(ta["edge_dst"], ta["edge_src"],
+                                    ta["n_edge"], dmax=self.dmax,
+                                    smax=int(ta["src_ids"].shape[1]))
         return kc
 
     # ------------------------------------------------------------------ bind
@@ -321,22 +342,25 @@ class PipelinedRunner:
                 "tile set is not structurally identical to this runner's: "
                 f"{tiles.shape_signature()} != {self.tiles.shape_signature()}")
         _check_reorder_mode(self.reorder_mode, reordering)
-        buckets: List[TileSet] = (
-            list(tiles.buckets) if isinstance(tiles, BucketedTileSet) else [tiles])
+        bucketed = isinstance(tiles, BucketedTileSet)
+        buckets: List[TileSet] = list(tiles.buckets) if bucketed else [tiles]
         tas = tuple(_tile_arrays(b) for b in buckets)
-        if self._kernels & set(S.PALLAS_KERNELS):
-            kcs = tuple(self._bucket_const(b, S.KERNEL_SPMM in self._kernels)
-                        for b in buckets)
-        else:
-            kcs = tuple({} for _ in buckets)
         # the online-softmax state cannot be merged across buckets, so the
         # segment-softmax block always runs over the unbucketed tile batch:
         # bound apart for bucketed tiles, else the one bucket (``_run``)
+        count = self._count_buckets or (self._count_source and not bucketed)
+        if self._kernels & set(S.PALLAS_KERNELS):
+            kcs = tuple(self._tile_const(b, ta, count)
+                        for b, ta in zip(buckets, tas))
+        else:
+            kcs = tuple({} for _ in buckets)
+        n_counts = len(buckets) if count else 0
         ta0 = kc0 = None
-        if (S.KERNEL_SEGMENT_SOFTMAX in self._kernels
-                and isinstance(tiles, BucketedTileSet)):
+        if S.KERNEL_SEGMENT_SOFTMAX in self._kernels and bucketed:
             ta0 = _tile_arrays(tiles.source)
-            kc0 = self._tile_const(tiles.source)
+            kc0 = self._tile_const(tiles.source, ta0, self._count_source)
+            n_counts += self._count_source
+        self.bound_counts = n_counts
         rel = None
         if self.n_types is not None:
             from ..kernels.relation.ops import layout_operands
@@ -506,14 +530,6 @@ class PipelinedRunner:
                                                [genv[i] for i in n.inputs])
             return genv[_edge_result(g)][..., 0]
 
-        def edge_count(ta):
-            """(T, Dmax, Smax) parallel-edge count of one tile batch.  Every
-            grid block calls it; XLA merges the calls into one scatter."""
-            return densify_edge_weights(
-                jnp.ones(ta["edge_src"].shape, jnp.float32), ta["edge_dst"],
-                ta["edge_src"], ta["n_edge"], dmax=dmax,
-                smax=int(ta["src_ids"].shape[1]))
-
         def unpad(val):
             """(P, Dmax, d) partition-padded -> (V, d) vertex store."""
             flat = jnp.where(pad_valid, val, 0.0).reshape(P * dmax, -1)
@@ -573,7 +589,7 @@ class PipelinedRunner:
                             vals = src_value(senv, g.src_value_id,
                                              ta0["src_ids"])
                         with _stage("densify"):
-                            scores = grid_edge_scores(s, edge_count(ta0))
+                            scores = grid_edge_scores(s, kc0[_COUNT])
                     else:
                         def tile_se(xs):
                             senv = eval_vertex(xs["src_ids"], phase.src.nodes)
@@ -643,13 +659,13 @@ class PipelinedRunner:
                     else:
                         smax = int(ta["src_ids"].shape[1])
                         if w is None:
-                            adj = kc["adj"]
+                            adj = kc[_COUNT]
                         elif on_grid:
                             # cnt·w is the sum of cnt parallel edges' equal
                             # weights; a select, since off-edge grid cells
                             # may be inf or NaN and inf·0 is NaN
                             with _stage("densify"):
-                                cnt = edge_count(ta)
+                                cnt = kc[_COUNT]
                                 adj = jnp.where(cnt > 0, cnt * w, 0.0)
                         else:    # weighted: densify the runtime edge weights
                             with _stage("densify"):

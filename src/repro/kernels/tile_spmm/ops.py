@@ -80,6 +80,17 @@ def densify_edge_weights(weights, edge_dst, edge_src, n_edge, *,
     return jax.vmap(per_tile)(w, edge_dst, edge_src)
 
 
+@functools.partial(jax.jit, static_argnames=("dmax", "smax"))
+def edge_count(edge_dst, edge_src, n_edge, *, dmax: int, smax: int):
+    """(T, dmax, smax) parallel-edge count of one tile batch on the device:
+    f32 ones summed per (destination, source) cell, exactly the adjacency
+    :func:`densify_tiles` builds on the host.  ``PipelinedRunner.bind``
+    builds it once per tile batch; the forward only reads it."""
+    return densify_edge_weights(jnp.ones(edge_src.shape, jnp.float32),
+                                edge_dst, edge_src, n_edge, dmax=dmax,
+                                smax=smax)
+
+
 @functools.partial(jax.jit, static_argnames=("dmax",))
 def densify_edge_scores(scores, edge_dst, n_edge, *, dmax: int):
     """Per-edge-COLUMN score densification for the segment-softmax kernel.

@@ -26,13 +26,12 @@ import itertools
 import threading
 from typing import Dict, List, Optional, Sequence, Union
 
-import jax
 import numpy as np
 from jax.profiler import TraceAnnotation
 
 from ..core import compiler as C
 from ..core import schedule as S
-from ..core.pipeline import (PipelinedRunner, ShardedRunner,
+from ..core.pipeline import (PipelinedRunner, ShardedRunner, host_leaves,
                              shard_layout_signature)
 from ..gnn import models as M
 from ..gnn.graphs import Graph, batch_graphs
@@ -284,7 +283,7 @@ class InferenceServer:
             with TraceAnnotation("serve.bind", request=rid) as span:
                 operands = runner.bind(tiles, reordering=ro)
                 if span.is_enabled():
-                    _count_h2d(span, jax.tree_util.tree_leaves(operands))
+                    _count_h2d(span, host_leaves(operands))
             with TraceAnnotation("serve.dispatch", request=rid) as span:
                 if span.is_enabled():
                     _count_h2d(span, [a for a in (*merged_inputs.values(),

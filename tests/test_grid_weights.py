@@ -11,7 +11,7 @@ to the kernel with per-source-row values.  Pinned here: both grid paths
 match the whole-graph reference and the per-slot path, stay finite where
 off-edge grid cells are not, engage exactly where the weight or score is
 vertex-only, leave no per-slot gather in the ``zipper.edge`` stage, and
-share one count scatter per compiled forward, scoped to ``zipper.densify``.
+share one edge count that ``bind`` scattered: the forward scatters none.
 """
 import re
 
@@ -338,8 +338,9 @@ def test_no_per_slot_work_in_the_gat_program(n_buckets, monkeypatch):
 
 @pytest.mark.parametrize("model", ["gat", "gcn"])
 def test_one_count_scatter_per_forward(model):
-    """Both layers' grid blocks read one edge-count scatter in the compiled
-    forward, and it stays under the ``zipper.densify`` scope."""
+    """Both layers' grid blocks read one edge count, scattered once per
+    ``bind`` and handed to the compiled forward as one operand: the forward
+    itself scatters nothing over the tile grid."""
     g = _graph()
     tiles = _tiles(g)
     tr, c = _gat() if model == "gat" else _gcn()
@@ -350,7 +351,10 @@ def test_one_count_scatter_per_forward(model):
     grid = f"f32[{T},{dmax},{tiles.s_max}]"
     hlo = runner._jitted.lower(*runner._args(inputs, params, None)) \
         .compile().as_text()
-    scatters = [ln for ln in hlo.splitlines()
+    assert runner.bound_counts == 1
+    assert not [ln for ln in hlo.splitlines()
                 if " scatter(" in ln and f"= {grid}" in ln]
-    assert len(scatters) == 1
-    assert 'op_name="jit(_run)/zipper.densify/' in scatters[0]
+    entry = hlo[hlo.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}")]
+    assert len([ln for ln in entry.splitlines()
+                if " parameter(" in ln and f"= {grid}" in ln]) == 1

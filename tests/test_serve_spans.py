@@ -3,7 +3,7 @@
 ``InferenceServer.submit`` opens ``serve.submit`` and, per size class,
 ``serve.run_group`` with its stages nested inside; every span carries the
 request's id, and ``serve.bind`` / ``serve.dispatch`` count the arrays and
-bytes they hand to the device.  The tree is read back from a real
+bytes they hand to the device (not the edge counts ``bind`` builds there).  The tree is read back from a real
 ``jax.profiler`` trace on the CPU.
 """
 import jax
@@ -107,9 +107,13 @@ def test_bind_counts_the_bound_operands(traced):
     binds = [s for s in spans if s[2] == "serve.bind"]
     assert len(binds) == len(bound)
     for s, ops in zip(binds, bound):
-        leaves = jax.tree_util.tree_leaves(ops)
+        leaves = pipeline.host_leaves(ops)
         assert s[3]["arrays"] == len(leaves)
         assert s[3]["bytes"] == sum(a.nbytes for a in leaves) > 0
+        # the edge count is scattered on the device at bind: no copy
+        counts = [kc["cnt"] for kc in ops[1]]
+        assert len(jax.tree_util.tree_leaves(ops)) == len(leaves) + len(counts)
+        assert counts and all(c.ndim == 3 for c in counts)
 
 
 def test_dispatch_counts_host_arrays_and_lookup_reports_hits(traced):

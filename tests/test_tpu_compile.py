@@ -8,7 +8,8 @@ compiles in both forms the runner calls: one column per edge slot and one
 per source on the tile grid (``segment_softmax_grid``).  The relation kernel
 compiles at the R-GCN cell's shape and as one dense matrix.  The runner's
 whole forward compiles too, for gcn and gat on tiny COO tiles, to check that
-the tile grid's edge-count scatter keeps its ``zipper.densify`` op name.  Two
+it scatters nothing over the tile grid (the edge count is bound at ``bind``)
+and that the grid select keeps its ``zipper.densify`` op name.  Two
 kinds of tile shapes for the kernels:
 
 * ``serving`` — a 16-graph request of ~64 V / 256 E graphs, canonicalized
@@ -25,6 +26,7 @@ file loads the TPU library.
 import functools
 import os
 import pathlib
+import re
 import sys
 
 import jax
@@ -179,13 +181,19 @@ def test_relation_sum_kernel_compiles_for_v5e(one_chip, mosaic):
     assert compiled.out_info.shape == (P, S, W)
 
 
-@pytest.mark.parametrize("model", ["gcn", "gat"])
-def test_count_scatter_keeps_its_scope_on_v5e(model, one_chip, mosaic):
-    """The tile grid's edge-count scatter, called by both layers' grid
-    blocks, compiles for the chip to one fusion that keeps the
-    ``zipper.densify`` op name the device trace attributes stages by."""
-    import re
+def _elements(ln: str):
+    """Element count of an f32 HLO instruction's result (``None``: not one)."""
+    m = re.search(r"= f32\[([\d,]*)\]", ln)
+    return None if m is None else int(np.prod(
+        [int(d) for d in m.group(1).split(",") if d]))
 
+
+@pytest.mark.parametrize("model", ["gcn", "gat"])
+def test_forward_reads_the_bound_count_on_v5e(model, one_chip, mosaic):
+    """The tile grid's edge count is bound at ``bind``: the forward compiled
+    for the chip scatters nothing over the (T, Dmax, Smax) grid, and the
+    fusions holding the grid select that reads the count keep the
+    ``zipper.densify`` op name the device trace attributes stages by."""
     from repro.core import compiler, pipeline
     from repro.gnn import models
 
@@ -199,17 +207,22 @@ def test_count_scatter_keeps_its_scope_on_v5e(model, one_chip, mosaic):
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
         runner._args(models.init_inputs(tr, g, 1),
                      models.init_params(tr, 1), None))
-    text = runner._jitted.lower(*args).compile().as_text()
+    assert runner.bound_counts == 1
+    lines = runner._jitted.lower(*args).compile().as_text().splitlines()
 
     cells = ts.n_tiles * int(ts.part_size.max()) * ts.s_max
-    comp, holder = None, []           # fused computations holding the count
-    for ln in text.splitlines():
+    assert not [ln for ln in lines
+                if " scatter(" in ln and _elements(ln) == cells]
+    comp, holder = None, set()        # fused computations of the grid select
+    for ln in lines:
         if ln and not ln[0].isspace() and ln.rstrip().endswith("{"):
             comp = ln.split()[0].lstrip("%")
-        elif " scatter(" in ln and f"= f32[{cells}]" in ln:
-            holder.append(comp)
-    assert len(holder) == 1
-    calls = [ln for ln in text.splitlines()
-             if re.search(rf"calls=%?{re.escape(holder[0])}\b", ln)]
-    assert len(calls) == 1
-    assert 'op_name="jit(_run)/zipper.densify/' in calls[0]
+        elif (" select(" in ln and _elements(ln) == cells
+              and "zipper.densify/" in ln):
+            holder.add(comp)
+    assert holder
+    for name in holder:
+        calls = [ln for ln in lines
+                 if re.search(rf"calls=%?{re.escape(name)}\b", ln)]
+        assert calls and all('op_name="jit(_run)/zipper.densify/' in ln
+                             for ln in calls)
